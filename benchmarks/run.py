@@ -33,7 +33,11 @@ def main() -> None:
                          "(fails if nothing was recorded)")
     args = ap.parse_args()
 
+    from repro.compile_cache import use_compile_cache
+
     from . import kernels_bench, paper_tables, serve_bench
+
+    use_compile_cache()
 
     if args.profile:
         from repro.kernels import dispatch

@@ -31,10 +31,11 @@ time comparisons matter:
   the chunked rows also price the TTFT scheduling the gate protects.
 
 The ``serve_sharded_*`` rows time the mesh-sharded engine (TP over the
-KV pool's head axis; CP over the decode window) in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count`` forcing the mesh's
-device count — ``us_per_call`` stays microseconds per generated token
-and ``derived`` is **per-device** tok/s (aggregate / mesh size), the
+KV pool's head axis; CP over the decode window) on meshes cut from this
+process's devices (a CPU run forces 2 for ``--tiny``, 4 for the full
+shapes, with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``) —
+``us_per_call`` stays microseconds per generated token and ``derived``
+is **per-device** tok/s (aggregate / mesh size), the
 scaling number the nightly lane tracks.  On CPU the virtual devices
 share cores, so these rows price the sharding machinery (shard_map
 dispatch, o-gather, constraint re-application), not real-accelerator
@@ -56,6 +57,7 @@ the module-level ``OBS`` dict — ``benchmarks/run.py`` persists it as the
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 
 import jax
@@ -136,69 +138,18 @@ def run(tiny: bool = False):
     return rows
 
 
-_SHARDED_DRIVER = """
-import dataclasses
-import json
-import time
-
-import jax
-import numpy as np
-
-from repro import configs
-from repro.core.policy import PrecisionPolicy
-from repro.dist import serve_pod_ctx
-from repro.launch.mesh import make_serve_mesh
-from repro.models import transformer as T
-from repro.serve import EngineOptions, ServeEngine
-
-tp, cp, bits, fused = {tp}, {cp}, {bits}, {fused}
-lens, max_new, slots, waves = {lens}, {max_new}, {slots}, {waves}
-cfg = configs.get_smoke("llama3_8b")
-if tp > cfg.num_kv_heads:
-    cfg = dataclasses.replace(cfg, num_kv_heads=tp)
-params = T.init_params(cfg, jax.random.PRNGKey(0))
-prompts = [np.asarray(jax.random.randint(jax.random.PRNGKey(100 + i),
-                                         (n,), 0, cfg.vocab_size))
-           for i, n in enumerate(lens)]
-max_len = max(lens) + max_new
-if max_len % cp:
-    max_len += cp - max_len % cp          # CP shards the window evenly
-eng = ServeEngine(cfg, PrecisionPolicy("float32", fused_decode=fused),
-                  params, max_slots=slots, max_len=max_len,
-                  options=EngineOptions(cache_bits=bits),
-                  dist=serve_pod_ctx(tp=tp, cp=cp),
-                  mesh=make_serve_mesh(tp=tp, cp=cp))
-
-def wave():
-    uids = [eng.submit(p, max_new=max_new) for p in prompts]
-    t0 = time.perf_counter()
-    out = eng.run()
-    dt = time.perf_counter() - t0
-    assert all(len(out[u]) == max_new for u in uids), "short generation"
-    return sum(len(out[u]) for u in uids), dt
-
-wave()                                    # warmup: pays every compile
-best = None
-for _ in range(waves):
-    toks, dt = wave()
-    if best is None or dt < best[1]:
-        best = (toks, dt)
-print(json.dumps({{"toks": best[0], "dt": best[1]}}))
-"""
-
-
 def _sharded_rows(lens, max_new, slots, *, tiny):
-    """Mesh-sharded engine rows, one subprocess per mesh shape.
+    """Mesh-sharded engine rows, built in this process from its devices.
 
-    The device-count flag must be set before jax initializes, hence the
-    subprocess (the bench process itself already holds 1 device).  The
-    timer brackets only ``eng.run()`` inside the child — interpreter and
-    compile startup never touch the row.
+    A TPU belongs to one process, so the meshes are cut from the devices
+    this process already sees; a CPU run forces host devices before it
+    starts (``XLA_FLAGS=--xla_force_host_platform_device_count=N``).
+    Fewer devices than a mesh needs raise ``MeshConfigError``.  The timer
+    brackets only ``eng.run()``; the warmup wave pays every compile.
     """
-    import json as _json
-    import os
-    import subprocess
-    import sys
+    from repro.dist import serve_pod_ctx
+    from repro.launch.mesh import make_serve_mesh
+    from repro.serve import EngineOptions
 
     variants = [("serve_sharded_tp2_f32", 2, 1, 0, False),
                 ("serve_sharded_tp2_int8_fused", 2, 1, 8, True)]
@@ -207,21 +158,25 @@ def _sharded_rows(lens, max_new, slots, *, tiny):
                      ("serve_sharded_cp2_f32", 1, 2, 0, False)]
     rows = []
     for name, tp, cp, bits, fused in variants:
-        ndev = tp * cp
-        script = _SHARDED_DRIVER.format(
-            tp=tp, cp=cp, bits=bits, fused=fused, lens=tuple(lens),
-            max_new=max_new, slots=slots, waves=3 if tiny else 1)
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
-                            f"{ndev} " + env.get("XLA_FLAGS", "")).strip()
-        res = subprocess.run([sys.executable, "-c", script],
-                             capture_output=True, text=True, env=env,
-                             timeout=900)
-        if res.returncode != 0:
-            raise RuntimeError(f"{name} driver failed:\n{res.stderr}")
-        out = _json.loads(res.stdout.strip().splitlines()[-1])
-        rows.append((name, out["dt"] / out["toks"] * 1e6,
-                     out["toks"] / out["dt"] / ndev))
+        mesh = make_serve_mesh(tp=tp, cp=cp)
+        cfg = configs.get_smoke("llama3_8b")
+        if tp > cfg.num_kv_heads:
+            cfg = dataclasses.replace(cfg, num_kv_heads=tp)
+        params = T.init_params(cfg, jax.random.PRNGKey(0))
+        prompts = [np.asarray(jax.random.randint(jax.random.PRNGKey(100 + i),
+                                                 (n,), 0, cfg.vocab_size))
+                   for i, n in enumerate(lens)]
+        max_len = max(lens) + max_new
+        max_len += -max_len % cp              # CP shards the window evenly
+        eng = ServeEngine(cfg, PrecisionPolicy("float32", fused_decode=fused),
+                          params, max_slots=slots, max_len=max_len,
+                          options=EngineOptions(cache_bits=bits),
+                          dist=serve_pod_ctx(tp=tp, cp=cp), mesh=mesh)
+        _wave(eng, prompts, max_new)          # warmup: pays every compile
+        best = min((_wave(eng, prompts, max_new)
+                    for _ in range(3 if tiny else 1)), key=lambda r: r[1])
+        toks, dt = best
+        rows.append((name, dt / toks * 1e6, toks / dt / (tp * cp)))
     return rows
 
 

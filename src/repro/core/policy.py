@@ -18,6 +18,8 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
+
 from .formats import (
     BFLOAT16,
     FLOAT8_E4M3,
@@ -30,6 +32,15 @@ from .formats import (
     Observe,
     container_exact_bits,
 )
+
+# Precision of every f32 dot, in XLA and in the Pallas kernels.  The TPU
+# MXU multiplies bf16: at DEFAULT precision XLA and Mosaic feed an f32
+# operand to it as one bf16 value, 8 significant bits — fewer than a
+# DFXP computation operand holds (comp_width 10), so the chip would
+# round the paper's operands below their width.  HIGHEST splits each
+# operand into bf16 pieces and keeps f32 products: exact for operands of
+# up to 16 significant bits.  On the CPU it changes nothing.
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
 
 _FLOATS = {
     "float32": FLOAT32,

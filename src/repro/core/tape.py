@@ -18,7 +18,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from .policy import PrecisionPolicy
+from .policy import MATMUL_PRECISION, PrecisionPolicy
 from .quant import q_stats, qbound, ste_quant
 
 Array = jax.Array
@@ -148,9 +148,11 @@ class QTape:
         wq = self.weight(name, w).astype(x.dtype)
         if transpose_b:
             y = jnp.einsum("...d,vd->...v", x, wq,
+                           precision=MATMUL_PRECISION,
                            preferred_element_type=jnp.float32)
         else:
-            y = jnp.matmul(x, wq, preferred_element_type=jnp.float32)
+            y = jnp.matmul(x, wq, precision=MATMUL_PRECISION,
+                           preferred_element_type=jnp.float32)
         return y.astype(x.dtype)
 
 

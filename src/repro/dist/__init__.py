@@ -14,15 +14,11 @@ paper's low-precision formats must flow through:
   * :mod:`repro.dist.cp_attention` — context-parallel GQA decode attention
     (KV window sharded, softmax statistics combined exactly).
 """
-from repro import _jax_compat
-
-_jax_compat.install()
-
-from .context import (  # noqa: E402,F401
+from .context import (  # noqa: F401
     DistCtx,
     MeshConfigError,
     multi_pod_ctx,
     serve_pod_ctx,
     single_pod_ctx,
 )
-from .sharding import ShardingRules  # noqa: E402,F401
+from .sharding import ShardingRules  # noqa: F401

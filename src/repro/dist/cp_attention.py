@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro._jax_compat import ambient_mesh
+from repro.core.policy import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -40,6 +40,7 @@ def _partial_attention(q, ck, cv, pos, q_pos, *, num_heads: int,
     K, G = num_kv_heads, num_heads // num_kv_heads
     qg = q.astype(jnp.float32).reshape(B, Sq, K, G, head_dim)
     s = jnp.einsum("bqkgh,bskh->bkgqs", qg, ck.astype(jnp.float32),
+                   precision=MATMUL_PRECISION,
                    preferred_element_type=jnp.float32)
     s = s / math.sqrt(head_dim)
     valid = (pos[:, None, :] >= 0) & (q_pos[:, :, None] - pos[:, None, :]
@@ -52,6 +53,7 @@ def _partial_attention(q, ck, cv, pos, q_pos, *, num_heads: int,
     p = jnp.where(vexp, jnp.exp(s - m[..., None]), 0.0)
     l = p.sum(axis=-1)                                            # [B,K,G,Sq]
     o = jnp.einsum("bkgqs,bskh->bkgqh", p, cv.astype(jnp.float32),
+                   precision=MATMUL_PRECISION,
                    preferred_element_type=jnp.float32)
     return o, l, m
 
@@ -92,9 +94,9 @@ def cp_decode_attention(q: Array, cache_k: Array, cache_v: Array,
               head_dim=head_dim)
 
     cp_axes = tuple(cp_axes)
-    mesh = ambient_mesh() if cp_axes else None
+    mesh = jax.sharding.get_abstract_mesh()
     cp_size = 0
-    if mesh is not None and all(a in mesh.shape for a in cp_axes):
+    if cp_axes and all(a in mesh.shape for a in cp_axes):
         cp_size = 1
         for a in cp_axes:
             cp_size *= mesh.shape[a]

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-from repro._jax_compat import ambient_mesh
 from repro.core.quant import exact_pow2
 from repro.kernels import dispatch
 from repro.kernels._tiling import resolve_interpret
@@ -41,8 +40,8 @@ def _tp_size(tp_axis: Optional[str], n_kv_heads: int) -> int:
     """
     if not tp_axis:
         return 0
-    mesh = ambient_mesh()
-    if mesh is None or tp_axis not in mesh.shape:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or tp_axis not in mesh.shape:
         return 0
     size = int(mesh.shape[tp_axis])
     return size if size > 1 and n_kv_heads % size == 0 else 0
@@ -94,7 +93,7 @@ def flash_decode(q: Array, k: Array, v: Array, pos: Array, q_pos: Array,
     W = k.shape[1]
     interpret = resolve_interpret(interpret)
     if block_w is None:
-        block_w = dispatch.attn_blocks_for(W, G, hd, width=width,
+        block_w = dispatch.attn_blocks_for(W, K, G, hd, width=width,
                                            interpret=interpret)
     block_w = min(block_w, W)
 
@@ -104,7 +103,7 @@ def flash_decode(q: Array, k: Array, v: Array, pos: Array, q_pos: Array,
         steps = jnp.stack([exact_pow2(jnp.asarray(k_exp, jnp.float32)),
                            exact_pow2(jnp.asarray(v_exp, jnp.float32))],
                           axis=-1)
-    qpos = jnp.asarray(q_pos, jnp.int32).reshape(B, 1)
+    qpos = jnp.asarray(q_pos, jnp.int32).reshape(B)
 
     return flash_decode_call(q.astype(jnp.float32), k, v,
                              pos.astype(jnp.int32), qpos, steps, width=width,
@@ -158,7 +157,7 @@ def flash_prefill(q: Array, k_new: Array, v_new: Array, k: Array, v: Array,
     W = k.shape[1]
     interpret = resolve_interpret(interpret)
     if block_w is None:
-        block_w = dispatch.prefill_blocks_for(W, C, G, hd, width=width,
+        block_w = dispatch.prefill_blocks_for(W, C, K, G, hd, width=width,
                                               interpret=interpret)
     block_w = min(block_w, W)
 
@@ -168,8 +167,8 @@ def flash_prefill(q: Array, k_new: Array, v_new: Array, k: Array, v: Array,
         steps = jnp.stack([exact_pow2(jnp.asarray(k_exp, jnp.float32)),
                            exact_pow2(jnp.asarray(v_exp, jnp.float32))],
                           axis=-1)
-    p0 = jnp.asarray(p0, jnp.int32).reshape(B, 1)
-    nv = jnp.asarray(n_valid, jnp.int32).reshape(B, 1)
+    p0 = jnp.asarray(p0, jnp.int32).reshape(B)
+    nv = jnp.asarray(n_valid, jnp.int32).reshape(B)
 
     return flash_prefill_call(q.astype(jnp.float32),
                               k_new.astype(jnp.float32),
@@ -233,10 +232,10 @@ def flash_decode_paged(q: Array, k: Array, v: Array, bt: Array, pos: Array,
                              jnp.asarray(v_exp, jnp.float32))
     n_pages, P = k.shape[:2]
     interpret = resolve_interpret(interpret)
-    dispatch.paged_attn_blocks_for(P, G, hd, width=width,
+    dispatch.paged_attn_blocks_for(P, K, G, hd, width=width,
                                    interpret=interpret)
     steps = _paged_steps(n_pages, k_exp, v_exp, width)
-    qpos = jnp.asarray(q_pos, jnp.int32).reshape(B, 1)
+    qpos = jnp.asarray(q_pos, jnp.int32).reshape(B)
     return flash_decode_paged_call(q.astype(jnp.float32), k, v,
                                    bt.astype(jnp.int32),
                                    pos.astype(jnp.int32), qpos, steps,
@@ -292,11 +291,11 @@ def flash_prefill_paged(q: Array, k_new: Array, v_new: Array, k: Array,
                              jnp.asarray(v_exp, jnp.float32))
     n_pages, P = k.shape[:2]
     interpret = resolve_interpret(interpret)
-    dispatch.paged_prefill_blocks_for(P, C, G, hd, width=width,
+    dispatch.paged_prefill_blocks_for(P, C, K, G, hd, width=width,
                                       interpret=interpret)
     steps = _paged_steps(n_pages, k_exp, v_exp, width)
-    p0 = jnp.asarray(p0, jnp.int32).reshape(B, 1)
-    nv = jnp.asarray(n_valid, jnp.int32).reshape(B, 1)
+    p0 = jnp.asarray(p0, jnp.int32).reshape(B)
+    nv = jnp.asarray(n_valid, jnp.int32).reshape(B)
     return flash_prefill_paged_call(q.astype(jnp.float32),
                                     k_new.astype(jnp.float32),
                                     v_new.astype(jnp.float32), k, v,

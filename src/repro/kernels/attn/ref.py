@@ -27,6 +27,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.policy import MATMUL_PRECISION
 from repro.core.quant import exact_pow2
 
 Array = jax.Array
@@ -53,6 +54,7 @@ def attend(qf: Array, kf: Array, vf: Array, pos: Array, q_pos: Array, *,
     int32 · ``q_pos``: [B] int32.  Returns [B, K, G, hd] float32.
     """
     s = jnp.einsum("bkgh,bwkh->bkgw", qf, kf,
+                   precision=MATMUL_PRECISION,
                    preferred_element_type=jnp.float32) * scale
     v4 = valid_mask(pos, q_pos, window=window, causal=causal)[:, None, None, :]
     s = jnp.where(v4, s, -1e30)
@@ -60,6 +62,7 @@ def attend(qf: Array, kf: Array, vf: Array, pos: Array, q_pos: Array, *,
     p = jnp.where(v4, jnp.exp(s - m), 0.0)
     el = jnp.sum(p, axis=-1, keepdims=True)
     o = jnp.einsum("bkgw,bwkh->bkgh", p, vf,
+                   precision=MATMUL_PRECISION,
                    preferred_element_type=jnp.float32)
     return o / jnp.maximum(el, 1e-30)
 
@@ -90,6 +93,7 @@ def chunk_attend(qf: Array, kf: Array, vf: Array, pos: Array, k_new: Array,
     row_ok = cpos[None, :] < n_valid[:, None]              # [B, C]
 
     sh = jnp.einsum("bckgh,bwkh->bkgcw", qf, kf,
+                    precision=MATMUL_PRECISION,
                     preferred_element_type=jnp.float32) * scale
     d = q_pos[:, :, None] - pos[:, None, :]                # [B, C, W]
     vh = (pos[:, None, :] >= 0) & (pos[:, None, :] < p0[:, None, None]) \
@@ -100,6 +104,7 @@ def chunk_attend(qf: Array, kf: Array, vf: Array, pos: Array, k_new: Array,
         vh = vh & (d < window)
 
     ss = jnp.einsum("bckgh,bjkh->bkgcj", qf, k_new,
+                    precision=MATMUL_PRECISION,
                     preferred_element_type=jnp.float32) * scale
     dj = cpos[:, None] - cpos[None, :]                     # [C, C]
     vs = row_ok[:, :, None] & row_ok[:, None, :]
@@ -118,8 +123,10 @@ def chunk_attend(qf: Array, kf: Array, vf: Array, pos: Array, k_new: Array,
     p = jnp.where(vcat, jnp.exp(s - m), 0.0)
     el = jnp.sum(p, axis=-1, keepdims=True)
     o = jnp.einsum("bkgcw,bwkh->bkgch", p[..., :W], vf,
+                   precision=MATMUL_PRECISION,
                    preferred_element_type=jnp.float32) \
         + jnp.einsum("bkgcj,bjkh->bkgch", p[..., W:], v_new,
+                     precision=MATMUL_PRECISION,
                      preferred_element_type=jnp.float32)
     o = o / jnp.maximum(el, 1e-30)
     return o.transpose(0, 3, 1, 2, 4)                      # [B, C, K, G, hd]

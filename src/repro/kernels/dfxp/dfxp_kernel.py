@@ -13,9 +13,11 @@ TPU adaptation notes:
     in SMEM as (1,1) scalars — ``exp2`` inside the kernel would re-derive
     them through a polynomial approximation (observed inexact on CPU XLA,
     see core.quant.exact_pow2);
-  * per-tile statistics go to a (grid_m, grid_n, 2) output summed by the
-    caller — cheaper than cross-tile atomics, exact because counts are
-    integers ≪ 2^24.
+  * per-tile statistics go to one (8, 128) f32 tile per grid step —
+    lanes 0 and 1 of row 0 hold the two counts, the rest is 0 — summed by
+    the caller: cheaper than cross-tile atomics, exact because counts are
+    integers ≪ 2^24, and a block shape Mosaic accepts (a (1, 1, 2) block
+    of a (grid_m, grid_n, 2) array is not).
 """
 from __future__ import annotations
 
@@ -24,6 +26,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+
+_STATS_TILE = (8, 128)
 
 
 def _kernel(step_ref, inv_ref, x_ref, y_ref, stats_ref, *, qmax: float,
@@ -35,8 +40,13 @@ def _kernel(step_ref, inv_ref, x_ref, y_ref, stats_ref, *, qmax: float,
     over = (m > qmax) | (m < qmin)
     over_half = (m > qmax / 2) | (m < qmin / 2)
     y_ref[...] = (jnp.clip(m, qmin, qmax) * step).astype(y_ref.dtype)
-    stats_ref[0, 0, 0] = jnp.sum(over.astype(jnp.float32))
-    stats_ref[0, 0, 1] = jnp.sum(over_half.astype(jnp.float32))
+    row = jax.lax.broadcasted_iota(jnp.int32, _STATS_TILE, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, _STATS_TILE, 1)
+    n_over = jnp.sum(over.astype(jnp.float32), keepdims=True)
+    n_half = jnp.sum(over_half.astype(jnp.float32), keepdims=True)
+    stats_ref[...] = jnp.where((row == 0) & (lane == 0), n_over,
+                               jnp.where((row == 0) & (lane == 1), n_half,
+                                         0.0))
 
 
 @functools.partial(jax.jit,
@@ -65,12 +75,14 @@ def dfxp_quantize_2d(x, step, inv_step, *, width: int, block_m: int = 256,
         ],
         out_specs=[
             pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1, 2), lambda i, j: (i, j, 0)),
+            pl.BlockSpec(_STATS_TILE, lambda i, j: (i, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((M, N), x.dtype),
-            jax.ShapeDtypeStruct((gm, gn, 2), jnp.float32),
+            jax.ShapeDtypeStruct((gm * _STATS_TILE[0], gn * _STATS_TILE[1]),
+                                 jnp.float32),
         ],
         interpret=interpret,
     )(step2, inv2, x)
-    return y, stats.sum(axis=(0, 1))
+    tiles = stats.reshape(gm, _STATS_TILE[0], gn, _STATS_TILE[1])
+    return y, tiles[:, 0, :, :2].sum(axis=(0, 1))

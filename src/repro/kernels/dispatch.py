@@ -22,14 +22,17 @@ four concerns the kernels themselves stay agnostic of:
 
 The same machinery dispatches the fused decode-attention kernel
 (:mod:`repro.kernels.attn`): :func:`attn_blocks_for` picks the split-K
-size from the same measured cache, keyed ``("attn", Ŵ, G, hd, width)``.
+size from the same measured cache, keyed ``("attn", Ŵ, K, G, hd, width)``.
 
 Measured entries **persist across processes**: every successful timing
-is serialized to ``.cache/autotune.json`` (override the path with the
-``REPRO_AUTOTUNE_CACHE`` env var) and loaded back on import, so a
-compiled-TPU autotune run survives restarts instead of re-timing every
+is serialized to ``<checkout>/.cache/autotune.json`` (override the path
+with the ``REPRO_AUTOTUNE_CACHE`` env var) and loaded back on import, so
+a compiled-TPU autotune run survives restarts instead of re-timing every
 bucket per process.  Heuristic fallbacks are never persisted — only
-numbers an actual backend produced.
+numbers an actual backend produced.  A candidate the compiler refuses is
+counted (the ``refused`` column of the profile table); a bucket whose
+every candidate is refused raises with the compiler's message rather
+than falling back to a tiling nobody compiled.
 
 ``QTape.dot`` calls :func:`tape_dot` when the policy enables the fused
 path (``PrecisionPolicy.fused_matmul``); numerics are bit-identical to
@@ -37,6 +40,7 @@ the ``ste_quant`` + ``jnp.matmul`` composite it replaces.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import functools
 import json
 import os
@@ -46,6 +50,7 @@ from typing import Dict, Optional, Set, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import CACHE_DIR
 from repro.kernels._tiling import (default_interpret, mm_blocks,
                                    resolve_interpret, round_up)
 from repro.kernels.qmatmul.ops import qmm
@@ -67,6 +72,10 @@ _CANDIDATES = [
 # Candidate split-K sizes (block_w) for the flash-decode attention kernel.
 _ATTN_CANDIDATES = [128, 256, 512, 1024, 2048]
 _VMEM_BUDGET = 8 * 1024 * 1024  # bytes of f32 tiles per grid step
+# What a compiler that refuses a tiling raises: Pallas' block-shape checks
+# (ValueError), unsupported lowerings, and Mosaic/XLA compile failures
+# such as a VMEM overflow.
+_REFUSALS = (ValueError, NotImplementedError, jax.errors.JaxRuntimeError)
 
 _AUTOTUNE: Dict[str, object] = {"measure": True, "reps": 3}
 _BLOCK_CACHE: Dict[tuple, Tuple[int, ...]] = {}
@@ -81,7 +90,7 @@ _MEASURED: Set[tuple] = set()   # keys whose blocks came from a real timing
 # profiling off the only cost is the ``_PROFILE["enabled"]`` check.
 _PROFILE: Dict[str, bool] = {"enabled": False}
 _PROF: Dict[tuple, dict] = {}
-_COMPILES = [0]                 # bumped by the _measure* loops
+_COUNTS = {"compiles": 0, "refused": 0}   # bumped by _time_candidates
 
 
 def profile_enable(on: bool = True) -> None:
@@ -92,29 +101,32 @@ def profile_enable(on: bool = True) -> None:
 
 def reset_profile() -> None:
     _PROF.clear()
-    _COMPILES[0] = 0
+    _COUNTS.update(compiles=0, refused=0)
 
 
 def profile_stats() -> Dict[tuple, dict]:
     """Copy of the per-bucket profile: ``{key: {calls, hits, misses,
-    compiles, measure_us, blocks}}`` (empty unless profiling ran)."""
+    compiles, refused, measure_us, blocks}}`` (empty unless profiling
+    ran)."""
     return {k: dict(v) for k, v in _PROF.items()}
 
 
 def _prof(key: tuple, *, hit: bool, blocks=None, measure_us: float = 0.0,
-          compiles: int = 0) -> None:
+          compiles: int = 0, refused: int = 0) -> None:
     if not _PROFILE["enabled"]:
         return
     d = _PROF.get(key)
     if d is None:
         d = _PROF[key] = {"calls": 0, "hits": 0, "misses": 0,
-                          "compiles": 0, "measure_us": 0.0, "blocks": None}
+                          "compiles": 0, "refused": 0, "measure_us": 0.0,
+                          "blocks": None}
     d["calls"] += 1
     if hit:
         d["hits"] += 1
     else:
         d["misses"] += 1
     d["compiles"] += compiles
+    d["refused"] += refused
     d["measure_us"] += measure_us
     if blocks is not None:
         d["blocks"] = tuple(blocks)
@@ -122,13 +134,13 @@ def _prof(key: tuple, *, hit: bool, blocks=None, measure_us: float = 0.0,
 
 def profile_table() -> str:
     """The dispatch profile as an aligned text table (one row per bucket)."""
-    rows = [("bucket", "calls", "hit", "miss", "compiles", "measure_ms",
-             "blocks")]
+    rows = [("bucket", "calls", "hit", "miss", "compiles", "refused",
+             "measure_ms", "blocks")]
     for key in sorted(_PROF, key=str):
         d = _PROF[key]
         rows.append(("|".join(map(str, key)), str(d["calls"]),
                      str(d["hits"]), str(d["misses"]), str(d["compiles"]),
-                     f"{d['measure_us'] / 1e3:.2f}",
+                     str(d["refused"]), f"{d['measure_us'] / 1e3:.2f}",
                      "x".join(map(str, d["blocks"] or ()))))
     widths = [max(len(r[i]) for r in rows) for i in range(len(rows[0]))]
     return "\n".join("  ".join(c.ljust(w) for c, w in zip(r, widths)).rstrip()
@@ -143,6 +155,7 @@ def profile_trace_counters(tracer) -> None:
         tracer.counter("dispatch/" + "|".join(map(str, key)),
                        {"calls": d["calls"], "hits": d["hits"],
                         "misses": d["misses"], "compiles": d["compiles"],
+                        "refused": d["refused"],
                         "measure_us": d["measure_us"]}, tid="dispatch")
 
 
@@ -168,7 +181,7 @@ def reset_autotune() -> None:
 # -- persistence ------------------------------------------------------------
 
 _CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
-_CACHE_DEFAULT = os.path.join(".cache", "autotune.json")
+_CACHE_DEFAULT = str(CACHE_DIR / "autotune.json")
 
 
 def _cache_path(path: Optional[str] = None) -> str:
@@ -214,12 +227,11 @@ def _valid_entry(key: tuple, blocks: tuple) -> bool:
     measured, so nothing ever re-measures the bucket).
     """
     if key[0] == "attn":
-        return (len(key) == 5 and len(blocks) == 1 and blocks[0] > 0
-                and _attn_fits(blocks[0], key[2], key[3], key[4] or None))
+        return (len(key) == 6 and len(blocks) == 1 and blocks[0] > 0
+                and _attn_fits(blocks[0], *key[2:5], key[5] or None))
     if key[0] == "prefill":
-        return (len(key) == 5 and len(blocks) == 1 and blocks[0] > 0
-                and _prefill_fits(blocks[0], key[1], key[2], key[3],
-                                  key[4] or None))
+        return (len(key) == 6 and len(blocks) == 1 and blocks[0] > 0
+                and _prefill_fits(blocks[0], *key[1:5], key[5] or None))
     if key[0] in ("nn", "nt", "tn"):
         return (len(key) == 4 and len(blocks) == 3
                 and all(b > 0 for b in blocks)
@@ -275,12 +287,74 @@ def _fits(blocks, R, C, D) -> bool:
     return vmem <= _VMEM_BUDGET
 
 
-def _measure(kind: str, R: int, C: int, D: int, width) -> Optional[tuple]:
-    """Time candidate tilings on dummy operands; return the fastest.
+def _time_candidates(key: tuple, cands, call) -> tuple:
+    """Time ``call(c)`` for every candidate; return the fastest.
 
-    None when no candidate compiled/timed (non-TPU backend) — the caller
-    falls back to the heuristic and does NOT persist the entry.
+    A candidate the compiler refuses is skipped and counted.  When it
+    refuses them all, raise with its last message: a heuristic tiling in
+    their place would only fail later, inside a serve or train step.
     """
+    reps = max(1, int(_AUTOTUNE["reps"]))
+    best, best_t, err = None, float("inf"), None
+    for blocks in cands:
+        try:
+            jax.block_until_ready(call(blocks))  # compile
+        except _REFUSALS as e:
+            _COUNTS["refused"] += 1
+            err = e
+            continue
+        _COUNTS["compiles"] += 1
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out = call(blocks)
+        jax.block_until_ready(out)
+        t = time.perf_counter() - t0
+        if t < best_t:
+            best, best_t = blocks, t
+    if best is None:
+        raise RuntimeError(
+            f"kernel dispatch: the compiler refused every candidate tiling "
+            f"of bucket {key} ({len(cands)} tried); last error: "
+            f"{type(err).__name__}: {err}") from err
+    return best
+
+
+def _on_one_device(fn):
+    """``fn()`` in a fresh thread, where the candidates really run.
+
+    Block selection runs while a jit traces the kernel's caller, often
+    under a mesh.  JAX keeps its trace and mesh contexts per thread: in
+    the caller's thread the candidates would only be traced (timing the
+    tracing, and never meeting a compile error) or partitioned over the
+    mesh, which a Mosaic kernel refuses; in a fresh thread they compile
+    and run eagerly on the default device.
+    """
+    with concurrent.futures.ThreadPoolExecutor(max_workers=1) as pool:
+        return pool.submit(fn).result()
+
+
+def _measured(key: tuple, measure, default: tuple) -> tuple:
+    """Cache miss: measure (or take the heuristic), record, persist."""
+    n0, r0, t0 = (_COUNTS["compiles"], _COUNTS["refused"],
+                  time.perf_counter())
+    try:
+        measured = _on_one_device(measure) if _AUTOTUNE["measure"] else None
+    finally:   # a bucket whose every candidate was refused still shows
+        _prof(key, hit=False, measure_us=(time.perf_counter() - t0) * 1e6,
+              compiles=_COUNTS["compiles"] - n0,
+              refused=_COUNTS["refused"] - r0)
+    blocks = measured or default
+    if key in _PROF:
+        _PROF[key]["blocks"] = tuple(blocks)
+    _BLOCK_CACHE[key] = blocks
+    if measured:
+        _MEASURED.add(key)
+        save_autotune()
+    return blocks
+
+
+def _measure(kind: str, R: int, C: int, D: int, width) -> tuple:
+    """Time candidate tilings on dummy operands; return the fastest."""
     if kind == "nn":
         sa, sb = (R, D), (D, C)
     elif kind == "nt":
@@ -290,27 +364,13 @@ def _measure(kind: str, R: int, C: int, D: int, width) -> Optional[tuple]:
     a = jnp.zeros(sa, jnp.float32)
     b = jnp.zeros(sb, jnp.float32)
     e = jnp.float32(0.0)
-    best, best_t = None, float("inf")
-    reps = max(1, int(_AUTOTUNE["reps"]))
     cands = [c for c in _CANDIDATES if _fits(c, R, C, D)]
     if not cands:
         cands = [mm_blocks(kind, R, C, D)]
-    for blocks in cands:
-        fn = lambda: qmm(a, b, e, e, kind=kind, width_a=width,
-                         width_b=width, blocks=blocks, interpret=False)
-        try:
-            jax.block_until_ready(fn())  # compile
-        except Exception:  # tiling rejected by the compiler — skip
-            continue
-        _COMPILES[0] += 1
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            out = fn()
-        jax.block_until_ready(out)
-        t = time.perf_counter() - t0
-        if t < best_t:
-            best, best_t = blocks, t
-    return best
+    return _time_candidates(
+        (kind, R, C, D), cands,
+        lambda blocks: qmm(a, b, e, e, kind=kind, width_a=width,
+                           width_b=width, blocks=blocks, interpret=False))
 
 
 def blocks_for(kind: str, R: int, C: int, D: int, *, interpret: bool,
@@ -332,17 +392,8 @@ def blocks_for(kind: str, R: int, C: int, D: int, *, interpret: bool,
     key = (kind, _bucket(R), _bucket(C), _bucket(D))
     blocks = _BLOCK_CACHE.get(key)
     if blocks is None:
-        n0, t0 = _COMPILES[0], time.perf_counter()
-        measured = (_measure(kind, key[1], key[2], key[3], width)
-                    if _AUTOTUNE["measure"] else None)
-        _prof(key, hit=False, blocks=measured or mm_blocks(kind, R, C, D),
-              measure_us=(time.perf_counter() - t0) * 1e6,
-              compiles=_COMPILES[0] - n0)
-        blocks = measured or mm_blocks(kind, R, C, D)
-        _BLOCK_CACHE[key] = blocks
-        if measured:
-            _MEASURED.add(key)
-            save_autotune()
+        blocks = _measured(key, lambda: _measure(kind, *key[1:], width),
+                           mm_blocks(kind, R, C, D))
     else:
         _prof(key, hit=True, blocks=blocks)
     return blocks
@@ -352,77 +403,77 @@ def blocks_for(kind: str, R: int, C: int, D: int, *, interpret: bool,
 # decode-attention split selection (repro.kernels.attn)
 # ---------------------------------------------------------------------------
 
-def _attn_fits(block_w: int, G: int, hd: int, width) -> bool:
-    kv_bytes = 1 if (width or 32) <= 8 else (2 if (width or 32) <= 16 else 4)
-    vmem = (2 * block_w * hd * kv_bytes          # k + v tiles
-            + 4 * (2 * G * block_w               # scores + probs
-                   + 2 * G * hd                  # q tile + acc scratch
-                   + 2 * G)                      # m/l scratch
-            + 4 * block_w)                       # pos tile
+def _kv_tile_bytes(rows: int, K: int, hd: int, width) -> int:
+    """VMEM bytes of one [rows, K, hd] K/V tile.
+
+    The kernels carry every kv head of a window block in one tile, so
+    its minor (K, hd) pair pads to the (sublane, 128) tiling: 8 sublanes
+    of f32, 16 of int16, 32 of int8.
+    """
+    nbytes = 1 if (width or 32) <= 8 else (2 if (width or 32) <= 16 else 4)
+    return rows * round_up(K, 32 // nbytes) * round_up(hd, 128) * nbytes
+
+
+def _f32_rows_bytes(K: int, rows: int, hd: int) -> int:
+    """VMEM bytes of a per-head f32 [K, rows, hd] tile (q, acc)."""
+    return 4 * K * round_up(rows, 8) * round_up(hd, 128)
+
+
+def _attn_fits(block_w: int, K: int, G: int, hd: int, width) -> bool:
+    vmem = (2 * _kv_tile_bytes(block_w, K, hd, width)  # k + v tiles
+            + 2 * _f32_rows_bytes(K, G, hd)            # q tile + acc
+            + 4 * 2 * round_up(G, 8) * block_w         # scores + probs
+            + 4 * 8 * block_w)                         # pos tile
     return vmem <= _VMEM_BUDGET
 
 
-def _measure_attn(W: int, G: int, hd: int, width) -> Optional[tuple]:
+def _default_split(W: int, fits) -> tuple:
+    """Heuristic split: the largest fitting candidate up to ``min(512,
+    Ŵ→128)``."""
+    ok = [c for c in _ATTN_CANDIDATES
+          if c <= min(512, round_up(W, 128)) and fits(c)]
+    return (ok[-1] if ok else _ATTN_CANDIDATES[0],)
+
+
+def _measure_attn(W: int, K: int, G: int, hd: int, width) -> tuple:
     """Time candidate split sizes for one attention bucket (compiled only)."""
     from repro.core.packed import container_dtype
     from repro.kernels.attn.ops import flash_decode
-    B, K = 1, 8
+    B = 1
     dt = jnp.float32 if width is None else container_dtype(width)
     q = jnp.zeros((B, K, G, hd), jnp.float32)
     kv = jnp.zeros((B, W, K, hd), dt)
     pos = jnp.zeros((B, W), jnp.int32)
     qp = jnp.full((B,), W - 1, jnp.int32)
     e = jnp.zeros((B,), jnp.float32)
-    reps = max(1, int(_AUTOTUNE["reps"]))
-    best, best_t = None, float("inf")
-    cands = [c for c in _ATTN_CANDIDATES
-             if c <= round_up(W, 128) and _attn_fits(c, G, hd, width)]
-    for bw in cands:
-        fn = lambda: flash_decode(q, kv, kv, pos, qp, e, e, width=width,
-                                  scale=1.0, block_w=bw, interpret=False)
-        try:
-            jax.block_until_ready(fn())  # compile
-        except Exception:  # tiling rejected by the compiler — skip
-            continue
-        _COMPILES[0] += 1
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            out = fn()
-        jax.block_until_ready(out)
-        t = time.perf_counter() - t0
-        if t < best_t:
-            best, best_t = (bw,), t
-    return best
+    cands = [(c,) for c in _ATTN_CANDIDATES
+             if c <= round_up(W, 128) and _attn_fits(c, K, G, hd, width)]
+    return _time_candidates(
+        ("attn", W, K, G, hd, width), cands,
+        lambda bw: flash_decode(q, kv, kv, pos, qp, e, e, width=width,
+                                scale=1.0, block_w=bw[0], interpret=False))
 
 
-def attn_blocks_for(W: int, G: int, hd: int, *, width=None,
+def attn_blocks_for(W: int, K: int, G: int, hd: int, *, width=None,
                     interpret: bool) -> int:
     """Split-K size (``block_w``) for the flash-decode kernel.
 
     Interpret mode returns the whole window — one grid step on exact
     full-shape blocks, which is the bit-equality contract against
     ``attn/ref.py`` (see :func:`blocks_for` for why padding/splitting
-    would drift ULPs on CPU).  Compiled buckets key on (Ŵ, G, hd, width)
-    and come from the measured cache, heuristic fallback
-    ``min(512, Ŵ→128)``.
+    would drift ULPs on CPU).  Compiled buckets key on
+    (Ŵ, K, G, hd, width) and come from the measured cache, heuristic
+    fallback :func:`_default_split`.
     """
     if interpret:
         _prof(("attn", "interp"), hit=True, blocks=(W,))
         return W
-    key = ("attn", _bucket(W), G, hd, width or 0)
+    key = ("attn", _bucket(W), K, G, hd, width or 0)
     blocks = _BLOCK_CACHE.get(key)
     if blocks is None:
-        n0, t0 = _COMPILES[0], time.perf_counter()
-        measured = (_measure_attn(key[1], G, hd, width)
-                    if _AUTOTUNE["measure"] else None)
-        blocks = measured or (min(512, round_up(W, 128)),)
-        _prof(key, hit=False, blocks=blocks,
-              measure_us=(time.perf_counter() - t0) * 1e6,
-              compiles=_COMPILES[0] - n0)
-        _BLOCK_CACHE[key] = blocks
-        if measured:
-            _MEASURED.add(key)
-            save_autotune()
+        blocks = _measured(
+            key, lambda: _measure_attn(key[1], K, G, hd, width),
+            _default_split(W, lambda c: _attn_fits(c, K, G, hd, width)))
     else:
         _prof(key, hit=True, blocks=blocks)
     return blocks[0]
@@ -438,23 +489,22 @@ def attn_blocks_for(W: int, G: int, hd: int, *, width=None,
 _PREFILL_MEASURE_W = 4096
 
 
-def _prefill_fits(block_w: int, C: int, G: int, hd: int, width) -> bool:
-    kv_bytes = 1 if (width or 32) <= 8 else (2 if (width or 32) <= 16 else 4)
+def _prefill_fits(block_w: int, C: int, K: int, G: int, hd: int,
+                  width) -> bool:
     rows = C * G
-    vmem = (2 * block_w * hd * kv_bytes          # k + v history tiles
-            + 4 * (2 * rows * max(block_w, C)    # scores + probs
-                   + 2 * rows * hd               # q tile + acc scratch
-                   + 2 * rows                    # m/l scratch
-                   + 2 * C * hd)                 # f32 chunk k/v tiles
-            + 4 * block_w)                       # pos tile
+    vmem = (2 * _kv_tile_bytes(block_w, K, hd, width)  # k + v history
+            + 2 * _kv_tile_bytes(C, K, hd, None)       # f32 chunk k/v
+            + 2 * _f32_rows_bytes(K, rows, hd)         # q tile + acc
+            + 4 * 2 * round_up(rows, 8) * max(block_w, C)  # scores+probs
+            + 4 * 8 * block_w)                         # pos tile
     return vmem <= _VMEM_BUDGET
 
 
-def _measure_prefill(C: int, G: int, hd: int, width) -> Optional[tuple]:
+def _measure_prefill(C: int, K: int, G: int, hd: int, width) -> tuple:
     """Time candidate split sizes for one prefill bucket (compiled only)."""
     from repro.core.packed import container_dtype
     from repro.kernels.attn.ops import flash_prefill
-    B, K, W = 1, 8, _PREFILL_MEASURE_W
+    B, W = 1, _PREFILL_MEASURE_W
     dt = jnp.float32 if width is None else container_dtype(width)
     q = jnp.zeros((B, C, K, G, hd), jnp.float32)
     kn = jnp.zeros((B, C, K, hd), jnp.float32)
@@ -463,58 +513,38 @@ def _measure_prefill(C: int, G: int, hd: int, width) -> Optional[tuple]:
     p0 = jnp.full((B,), W, jnp.int32)
     nv = jnp.full((B,), C, jnp.int32)
     e = jnp.zeros((B,), jnp.float32)
-    reps = max(1, int(_AUTOTUNE["reps"]))
-    best, best_t = None, float("inf")
-    cands = [c for c in _ATTN_CANDIDATES
-             if c <= round_up(W, 128) and _prefill_fits(c, C, G, hd, width)]
-    for bw in cands:
-        fn = lambda: flash_prefill(q, kn, kn, kv, kv, pos, p0, nv, e, e,
-                                   width=width, scale=1.0, block_w=bw,
-                                   interpret=False)
-        try:
-            jax.block_until_ready(fn())  # compile
-        except Exception:  # tiling rejected by the compiler — skip
-            continue
-        _COMPILES[0] += 1
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            out = fn()
-        jax.block_until_ready(out)
-        t = time.perf_counter() - t0
-        if t < best_t:
-            best, best_t = (bw,), t
-    return best
+    cands = [(c,) for c in _ATTN_CANDIDATES
+             if c <= round_up(W, 128)
+             and _prefill_fits(c, C, K, G, hd, width)]
+    return _time_candidates(
+        ("prefill", C, K, G, hd, width), cands,
+        lambda bw: flash_prefill(q, kn, kn, kv, kv, pos, p0, nv, e, e,
+                                 width=width, scale=1.0, block_w=bw[0],
+                                 interpret=False))
 
 
-def prefill_blocks_for(W: int, C: int, G: int, hd: int, *, width=None,
-                       interpret: bool) -> int:
+def prefill_blocks_for(W: int, C: int, K: int, G: int, hd: int, *,
+                       width=None, interpret: bool) -> int:
     """History split size (``block_w``) for the flash-prefill kernel.
 
     Interpret mode returns the whole window — one grid step on exact
     full-shape blocks, the bit-equality contract against
     ``attn/ref.chunk_attend``.  Compiled buckets key on
-    ``("prefill", C, G, hd, width)`` — W is deliberately not part of the
-    key (see ``_PREFILL_MEASURE_W``) — and come from the same persisted
-    measured cache as the decode splits; heuristic fallback
-    ``min(512, Ŵ→128)``.
+    ``("prefill", C, K, G, hd, width)`` — W is deliberately not part of
+    the key (see ``_PREFILL_MEASURE_W``) — and come from the same
+    persisted measured cache as the decode splits; heuristic fallback
+    :func:`_default_split`.
     """
     if interpret:
         _prof(("prefill", "interp"), hit=True, blocks=(W,))
         return W
-    key = ("prefill", C, G, hd, width or 0)
+    key = ("prefill", C, K, G, hd, width or 0)
     blocks = _BLOCK_CACHE.get(key)
     if blocks is None:
-        n0, t0 = _COMPILES[0], time.perf_counter()
-        measured = (_measure_prefill(C, G, hd, width)
-                    if _AUTOTUNE["measure"] else None)
-        blocks = measured or (min(512, round_up(W, 128)),)
-        _prof(key, hit=False, blocks=blocks,
-              measure_us=(time.perf_counter() - t0) * 1e6,
-              compiles=_COMPILES[0] - n0)
-        _BLOCK_CACHE[key] = blocks
-        if measured:
-            _MEASURED.add(key)
-            save_autotune()
+        blocks = _measured(
+            key, lambda: _measure_prefill(C, K, G, hd, width),
+            _default_split(W, lambda c: _prefill_fits(c, C, K, G, hd,
+                                                      width)))
     else:
         _prof(key, hit=True, blocks=blocks)
     return blocks[0]
@@ -524,7 +554,7 @@ def prefill_blocks_for(W: int, C: int, G: int, hd: int, *, width=None,
 # paged-attention split validation (repro.kernels.attn *_paged)
 # ---------------------------------------------------------------------------
 
-def paged_attn_blocks_for(P: int, G: int, hd: int, *, width=None,
+def paged_attn_blocks_for(P: int, K: int, G: int, hd: int, *, width=None,
                           interpret: bool) -> int:
     """Split size for the paged flash-decode kernel — always the page.
 
@@ -535,27 +565,28 @@ def paged_attn_blocks_for(P: int, G: int, hd: int, *, width=None,
     first call, not as a compiler OOM deep in a serve step.  Interpret
     mode has no VMEM and accepts any page.
     """
-    _prof(("paged_attn", P, G, hd, width or 0), hit=True, blocks=(P,))
-    if not interpret and not _attn_fits(P, G, hd, width):
+    _prof(("paged_attn", P, K, G, hd, width or 0), hit=True, blocks=(P,))
+    if not interpret and not _attn_fits(P, K, G, hd, width):
         raise ValueError(
-            f"page_size {P} (G={G}, hd={hd}, width={width}) exceeds the "
-            f"{_VMEM_BUDGET >> 20}MB VMEM tile budget of the paged "
+            f"page_size {P} (K={K}, G={G}, hd={hd}, width={width}) exceeds "
+            f"the {_VMEM_BUDGET >> 20}MB VMEM tile budget of the paged "
             "flash-decode kernel; use a smaller --page-size")
     return P
 
 
-def paged_prefill_blocks_for(P: int, C: int, G: int, hd: int, *, width=None,
-                             interpret: bool) -> int:
+def paged_prefill_blocks_for(P: int, C: int, K: int, G: int, hd: int, *,
+                             width=None, interpret: bool) -> int:
     """Split size for the paged flash-prefill kernel — always the page.
 
     Same contract as :func:`paged_attn_blocks_for`, with the chunk's
     ``C·G`` score rows included in the fit check.
     """
-    _prof(("paged_prefill", P, C, G, hd, width or 0), hit=True, blocks=(P,))
-    if not interpret and not _prefill_fits(P, C, G, hd, width):
+    _prof(("paged_prefill", P, C, K, G, hd, width or 0), hit=True,
+          blocks=(P,))
+    if not interpret and not _prefill_fits(P, C, K, G, hd, width):
         raise ValueError(
-            f"page_size {P} (C={C}, G={G}, hd={hd}, width={width}) exceeds "
-            f"the {_VMEM_BUDGET >> 20}MB VMEM tile budget of the paged "
+            f"page_size {P} (C={C}, K={K}, G={G}, hd={hd}, width={width}) "
+            f"exceeds the {_VMEM_BUDGET >> 20}MB VMEM tile budget of the paged "
             "flash-prefill kernel; use a smaller --page-size or chunk")
     return P
 

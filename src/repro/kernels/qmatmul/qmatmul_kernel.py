@@ -28,13 +28,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific memory spaces; interpret mode works without them
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from repro.core.policy import MATMUL_PRECISION
 
 # (lhs contracting dims, rhs contracting dims) per layout.
 _CONTRACT = {"nn": ((1,), (0,)), "nt": ((1,), (1,)), "tn": ((0,), (0,))}
@@ -65,6 +61,7 @@ def _kernel(scales_ref, a_ref, b_ref, c_ref, acc_ref, *, kind: str,
     bq = _load(b_ref, scales_ref, 1, width_b, cast)
     acc_ref[...] += jax.lax.dot_general(
         aq, bq, (_CONTRACT[kind], ((), ())),
+        precision=MATMUL_PRECISION,
         preferred_element_type=jnp.float32)
 
     @pl.when(r == nred - 1)
@@ -117,6 +114,6 @@ def qmm_2d(a, b, scales, *, kind: str, width_a, width_b, block_r: int,
         ],
         out_specs=pl.BlockSpec((block_r, block_c), lambda i, j, r: (i, j)),
         out_shape=jax.ShapeDtypeStruct((R, C), out_dtype),
-        scratch_shapes=[_VMEM((block_r, block_c), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_r, block_c), jnp.float32)],
         interpret=interpret,
     )(scales, a, b)

@@ -14,6 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.policy import MATMUL_PRECISION
 from repro.core.quant import exact_pow2
 
 
@@ -69,9 +70,11 @@ def qmatmul_ref(a, b, e_a, e_b, *, width: int, quant_a: bool = True,
     bq = _make_ste(width)(b, jnp.asarray(e_b, jnp.float32)) if quant_b else b
     if transpose_b:
         c = jax.lax.dot_general(aq, bq, (((1,), (1,)), ((), ())),
+                                precision=MATMUL_PRECISION,
                                 preferred_element_type=jnp.float32)
     else:
-        c = jnp.dot(aq, bq, preferred_element_type=jnp.float32)
+        c = jnp.dot(aq, bq, precision=MATMUL_PRECISION,
+                    preferred_element_type=jnp.float32)
     c = c.astype(a.dtype)
     if grad_width is not None:
         c = _make_gsite(grad_width)(c, jnp.asarray(e_g, jnp.float32))

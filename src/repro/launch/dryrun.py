@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
+# A compile-only tool: on a TPU host it must not take the chip, and its
+# --all children inherit the pin.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ both MUST precede any jax import: jax locks the backend on first init.
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this builds the real jit program (train_step for train shapes,
@@ -244,8 +247,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         t2 = time.time()
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # jax 0.4.x returns [dict], newer: dict
-        ca = ca[0] if ca else {}
     txt = compiled.as_text()
     if hlo_dir:
         _os.makedirs(hlo_dir, exist_ok=True)

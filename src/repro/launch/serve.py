@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.compile_cache import use_compile_cache
 from repro.core import ScaleState
 from repro.core.policy import PrecisionPolicy
 from repro.dist import MeshConfigError, serve_pod_ctx
@@ -135,7 +136,9 @@ def main(argv=None):
     ap.add_argument("--tp", type=int, default=1,
                     help="serving tensor parallelism: shard the KV pool's "
                          "kv-head axis over N devices (params replicated; "
-                         "greedy streams bit-identical to single-device)")
+                         "greedy streams bit-identical to single-device "
+                         "with interpret-mode kernels, logits within "
+                         "rounding on a TPU)")
     ap.add_argument("--cp", type=int, default=1,
                     help="serving context parallelism: shard the decode KV "
                          "window over N devices (long-context slots; exact "
@@ -195,6 +198,7 @@ def main(argv=None):
                          "compiles and measured us, printed as a table "
                          "(and dumped to the trace when --trace-out)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     demo_chaos = args.chaos is not None and not args.smoke \
         and args.arch == "llama3_8b"
@@ -295,12 +299,13 @@ def main(argv=None):
         from repro.obs import start_http_server
         server = start_http_server(eng.metrics.registry, args.metrics_port)
         print(f"metrics: http://127.0.0.1:{server.server_address[1]}/metrics")
-    uids = []
+    prompts = {}
     for i in range(args.num_requests):
         plen = lens[i % len(lens)]
         prompt = jax.random.randint(jax.random.PRNGKey(1000 + i), (plen,), 0,
                                     cfg.vocab_size)
-        uids.append(eng.submit(prompt, max_new=args.max_new))
+        prompts[eng.submit(prompt, max_new=args.max_new)] = prompt
+    uids = list(prompts)
     out = eng.run()
     stats = eng.stats()
     print(f"served {stats['requests_finished']} requests, "
@@ -345,7 +350,8 @@ def main(argv=None):
         print(f"metrics snapshot appended to {args.metrics_out}")
     if server is not None:
         server.shutdown()
-    return out
+    return {"tokens": out, "stats": stats, "prompts": prompts,
+            "status": {u: eng.status(u) for u in uids}, "engine": eng}
 
 
 if __name__ == "__main__":

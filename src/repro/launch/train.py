@@ -35,6 +35,7 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import use_compile_cache
 from repro.core.policy import PrecisionPolicy
 from repro.data import SyntheticLM
 from repro.models import transformer as T
@@ -119,6 +120,7 @@ def main(argv=None):
                     help="numerics sampling cadence in steps (default: the "
                          "controller's --update-interval)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     policy = build_policy(args)
@@ -146,12 +148,14 @@ def main(argv=None):
                    for i in range(args.calibrate_steps))
         init_exp = calibrate(obs_loss, params0, gs, policy, opt_cfg,
                              batches, steps=args.calibrate_steps)
+        del params0   # the run starts from fresh params; free the device copy
         print(f"calibrated {len(init_exp)} scale groups")
 
     params = T.init_params(cfg, jax.random.fold_in(key, 1))
     state = init_train_state(params, sgd_init(params) if
                              args.optimizer == "sgd" else adamw_init(params),
                              gs, policy, init_exp=init_exp)
+    del params        # DFXP/packed state holds its own rounded copy
 
     num_log = None
     if args.numerics_log:
@@ -250,7 +254,7 @@ def main(argv=None):
     if stop["now"]:
         return sys.exit(143)
     print("done")
-    return sup.state
+    return {**summary, "steps": [r.to_json() for r in sup.outcomes]}
 
 
 if __name__ == "__main__":

@@ -22,6 +22,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.policy import MATMUL_PRECISION
 from repro.core.tape import QTape
 
 Array = jax.Array
@@ -71,7 +72,8 @@ def apply_rope(x: Array, positions: Array, theta: float,
             total_repeat_length=hd // 2,
         )  # [hd/2] -> which position stream each freq dim uses
         pos = positions[sec_ids]                       # [hd/2, B, S]
-        angle = jnp.einsum("fbs,f->bsf", pos.astype(jnp.float32), freqs)
+        angle = jnp.einsum("fbs,f->bsf", pos.astype(jnp.float32), freqs,
+                           precision=MATMUL_PRECISION)
     else:
         angle = positions.astype(jnp.float32)[..., None] * freqs  # [B, S, hd/2]
     cos = jnp.cos(angle)[:, :, None, :]
@@ -157,10 +159,12 @@ def _sdpa(q, k, v, mask, scale) -> Array:
     G = H // K
     qg = q.reshape(B, Sq, K, G, hd)
     logits = jnp.einsum("bqkgh,bskh->bkgqs", qg, k,
+                        precision=MATMUL_PRECISION,
                         preferred_element_type=jnp.float32) * scale
     logits = jnp.where(mask[:, None, None, :, :], logits, -1e30)
     p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     o = jnp.einsum("bkgqs,bskh->bqkgh", p.astype(v.dtype), v,
+                   precision=MATMUL_PRECISION,
                    preferred_element_type=jnp.float32)
     return o.reshape(B, Sq, H, hd).astype(q.dtype)
 
@@ -230,6 +234,7 @@ def attention_prefill(params, spec: AttnSpec, x: Array, positions: Array,
         m, l, acc = carry
         kci, vci, pci = xs
         s = jnp.einsum("bqkgh,bckh->bkgqc", qg, kci,
+                       precision=MATMUL_PRECISION,
                        preferred_element_type=jnp.float32) * scale
         valid = _mask(q_pos, pci, window, spec.causal)  # [B, S, chunk]
         vexp = valid[:, None, None, :, :]
@@ -241,6 +246,7 @@ def attention_prefill(params, spec: AttnSpec, x: Array, positions: Array,
         l = l * corr + p.sum(axis=-1)
         acc = acc * corr[..., None] + jnp.einsum(
             "bkgqc,bckh->bkgqh", p, vci.astype(jnp.float32),
+            precision=MATMUL_PRECISION,
             preferred_element_type=jnp.float32)
         return (m_new, l, acc), None
 
@@ -394,9 +400,8 @@ def _replicate_attn_out(o: Array, dist) -> Array:
     """
     if dist is None or not getattr(dist, "active", False):
         return o
-    from repro._jax_compat import ambient_mesh
-    mesh = ambient_mesh()
-    if mesh is None:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return o
     from jax.sharding import NamedSharding, PartitionSpec
     return jax.lax.with_sharding_constraint(
@@ -519,6 +524,7 @@ def attention_decode(params, spec: AttnSpec, x: Array, pos: Array,
         cache_k, cache_v, cache_pos = codec.load(cache)
         qg = q.reshape(B, 1, K, G, hd)
         s = jnp.einsum("bqkgh,bskh->bkgqs", qg, cache_k,
+                       precision=MATMUL_PRECISION,
                        preferred_element_type=jnp.float32) * scale
         q_pos = positions if positions.ndim == 2 else positions[0]
         valid = _mask(q_pos, cache_pos, window, spec.causal)  # [B, 1, W]
@@ -526,6 +532,7 @@ def attention_decode(params, spec: AttnSpec, x: Array, pos: Array,
         s = jnp.where(valid[:, None, None, :, :], s, -1e30)
         p = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
         o = jnp.einsum("bkgqs,bskh->bqkgh", p, cache_v.astype(jnp.float32),
+                       precision=MATMUL_PRECISION,
                        preferred_element_type=jnp.float32)
         o = o.reshape(B, 1, spec.q_dim).astype(x.dtype)
     o = _replicate_attn_out(o, dist)

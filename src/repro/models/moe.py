@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.core.policy import MATMUL_PRECISION
 from repro.core.tape import QTape
 from repro.dist.context import DistCtx
 
@@ -77,6 +78,7 @@ def _moe_local(x, router_w, w_gate, w_up, w_down, scales, sinks,
 
     # --- routing (wide precision: documented deviation) -------------------
     logits = jnp.einsum("td,de->te", x, router_w,
+                        precision=MATMUL_PRECISION,
                         preferred_element_type=jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, ids = jax.lax.top_k(probs, k)                    # [T, k]
@@ -131,23 +133,29 @@ def _moe_local(x, router_w, w_gate, w_up, w_down, scales, sinks,
         Dl = w_gate.shape[1]
         xe_l = jax.lax.dynamic_slice_in_dim(xe, didx * Dl, Dl, axis=2)
         g = jnp.einsum("ecd,edf->ecf", xe_l, w_gate,
+                       precision=MATMUL_PRECISION,
                        preferred_element_type=jnp.float32)
         u = jnp.einsum("ecd,edf->ecf", xe_l, w_up,
+                       precision=MATMUL_PRECISION,
                        preferred_element_type=jnp.float32)
         g = jax.lax.psum(g, dist.fsdp_axis)
         u = jax.lax.psum(u, dist.fsdp_axis)
         h = tape.act(f"{prefix}/pre",
                      (jax.nn.silu(g) * u).astype(x.dtype))
         ye = jnp.einsum("ecf,efd->ecd", h, w_down,
+                        precision=MATMUL_PRECISION,
                         preferred_element_type=jnp.float32).astype(x.dtype)
         ye = jax.lax.all_gather(ye, dist.fsdp_axis, axis=2, tiled=True)
     else:
         g = jnp.einsum("ecd,edf->ecf", xe, w_gate,
+                       precision=MATMUL_PRECISION,
                        preferred_element_type=jnp.float32).astype(x.dtype)
         u = jnp.einsum("ecd,edf->ecf", xe, w_up,
+                       precision=MATMUL_PRECISION,
                        preferred_element_type=jnp.float32).astype(x.dtype)
         h = tape.act(f"{prefix}/pre", jax.nn.silu(g) * u)
         ye = jnp.einsum("ecf,efd->ecd", h, w_down,
+                        precision=MATMUL_PRECISION,
                         preferred_element_type=jnp.float32).astype(x.dtype)
 
     if dist.ep_axis:

@@ -22,6 +22,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.core.policy import MATMUL_PRECISION
 from repro.core.tape import QTape
 
 from .layers import init_dense, rmsnorm
@@ -150,18 +151,24 @@ def ssm_forward(params, spec: SSMSpec, u: Array, tape: QTape, prefix: str,
 
     # intra-chunk: Y[i] = sum_{j<=i} exp(acum_i - acum_j) (C_i·B_j) dt_j x_j
     G = jnp.einsum("bcin,bcjn->bcij", Cc, Bc,
+                   precision=MATMUL_PRECISION,
                    preferred_element_type=jnp.float32)               # [B,nc,Q,Q]
     diff = acum[:, :, :, None, :] - acum[:, :, None, :, :]           # [B,nc,Q,Q,H]
     causal = jnp.tril(jnp.ones((Q, Q), bool))
-    M = jnp.where(causal[None, None, :, :, None],
-                  jnp.exp(diff), 0.0) * G[..., None] * dtc[:, :, None, :, :]
+    # mask before the exp: above the diagonal diff > 0 and exp overflows
+    # for long chunks, and a masked inf still turns the backward into
+    # 0 * inf = NaN
+    M = jnp.exp(jnp.where(causal[None, None, :, :, None], diff, -jnp.inf)
+                ) * G[..., None] * dtc[:, :, None, :, :]
     y_intra = jnp.einsum("bcijh,bcjhp->bcihp", M, xc,
+                         precision=MATMUL_PRECISION,
                          preferred_element_type=jnp.float32)
 
     # per-chunk final state contribution: sum_j exp(acum_Q - acum_j) dt_j B_j x_j^T
     decay_to_end = jnp.exp(acum[:, :, -1:, :] - acum)                # [B,nc,Q,H]
     hc = jnp.einsum("bcjh,bcjn,bcjhp->bchpn",
                     decay_to_end * dtc, Bc, xc,
+                    precision=MATMUL_PRECISION,
                     preferred_element_type=jnp.float32)              # [B,nc,H,P,N]
 
     # carry chunk states
@@ -182,6 +189,7 @@ def ssm_forward(params, spec: SSMSpec, u: Array, tape: QTape, prefix: str,
     # inter-chunk: Y[i] += C_i · (exp(acum_i) h_prev_chunk)
     y_inter = jnp.einsum("bcin,bchpn,bcih->bcihp",
                          Cc, h_in, jnp.exp(acum),
+                         precision=MATMUL_PRECISION,
                          preferred_element_type=jnp.float32)
 
     y = (y_intra + y_inter + params["D"][None, None, None, :, None]
@@ -226,7 +234,8 @@ def ssm_decode(params, spec: SSMSpec, u: Array, cache: dict, tape: QTape,
     xbc = jnp.concatenate([x, Bm, Cm], axis=-1)                      # [B,1,conv]
     conv_buf = jnp.concatenate([cache["conv"], xbc], axis=1)         # [B,K,conv]
     w = params["conv_w"]
-    out = jnp.einsum("bkc,kc->bc", conv_buf, w) + params["conv_b"]
+    out = jnp.einsum("bkc,kc->bc", conv_buf, w,
+                     precision=MATMUL_PRECISION) + params["conv_b"]
     xbc1 = jax.nn.silu(out)[:, None, :]
     x, Bm, Cm = jnp.split(xbc1, [spec.d_inner, spec.d_inner + N], axis=-1)
     x = tape.act(f"{prefix}/x", x)
@@ -241,8 +250,10 @@ def ssm_decode(params, spec: SSMSpec, u: Array, cache: dict, tape: QTape,
 
     h = tape.state(f"{prefix}/state", cache["state"])
     h = (jnp.exp(a)[:, :, None, None] * h
-         + jnp.einsum("bh,bhp,bn->bhpn", dt, xh, Bv))
-    y = jnp.einsum("bn,bhpn->bhp", Cv, h) + params["D"][None, :, None] * xh
+         + jnp.einsum("bh,bhp,bn->bhpn", dt, xh, Bv,
+                      precision=MATMUL_PRECISION))
+    y = (jnp.einsum("bn,bhpn->bhp", Cv, h, precision=MATMUL_PRECISION)
+         + params["D"][None, :, None] * xh)
     y = y.reshape(B_, 1, spec.d_inner).astype(u.dtype)
     y = tape.act(f"{prefix}/y", y)
     y = rmsnorm(y * jax.nn.silu(z), params["norm_w"])

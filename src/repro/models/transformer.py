@@ -27,8 +27,9 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.policy import PrecisionPolicy
+from repro.core.policy import MATMUL_PRECISION, PrecisionPolicy
 from repro.core.tape import QTape
 from repro.dist.context import DistCtx
 
@@ -294,17 +295,25 @@ def _stage_group_names(cfg, stage, shared: bool):
 # ---------------------------------------------------------------------------
 
 def _ring_cache(k: Array, v: Array, cap: int):
-    """Pack full-sequence KV [B,S,K,hd] into a ring buffer of ``cap`` slots."""
+    """Pack full-sequence KV [B,S,K,hd] into a ring buffer of ``cap`` slots.
+
+    Position ``p`` lives in slot ``p % cap``; the slots are static, so the
+    ring is built by padding (``S <= cap``) or one rotation of the last
+    ``cap`` rows — slices, not a scatter (sibling scatters with shared
+    indices crash the TPU compiler's scatter emitter).
+    """
     B, S = k.shape[:2]
-    n_keep = min(S, cap)
-    pos_keep = jnp.arange(S - n_keep, S)
-    slots = pos_keep % cap
-    shape = (B, cap) + k.shape[2:]
-    ck = jnp.zeros(shape, k.dtype).at[:, slots].set(k[:, S - n_keep:])
-    cv = jnp.zeros(shape, v.dtype).at[:, slots].set(v[:, S - n_keep:])
-    cpos = jnp.full((B, cap), -1, jnp.int32).at[:, slots].set(
-        jnp.broadcast_to(pos_keep, (B, n_keep)).astype(jnp.int32))
-    return {"k": ck, "v": cv, "pos": cpos}
+    if S <= cap:
+        pad = ((0, 0), (0, cap - S)) + ((0, 0),) * (k.ndim - 2)
+        ck, cv = jnp.pad(k, pad), jnp.pad(v, pad)
+    else:   # kept row j holds position S - cap + j, i.e. slot (j + S) % cap
+        ck = jnp.roll(k[:, S - cap:], S % cap, axis=1)
+        cv = jnp.roll(v[:, S - cap:], S % cap, axis=1)
+    pos_keep = np.arange(max(S - cap, 0), S)
+    pos = np.full(cap, -1, np.int32)
+    pos[pos_keep % cap] = pos_keep
+    return {"k": ck, "v": cv,
+            "pos": jnp.broadcast_to(jnp.asarray(pos), (B, cap))}
 
 
 def _apply_block(cfg: ModelConfig, blk: SubBlock, pfx: str, bp, x, positions,
@@ -399,10 +408,12 @@ def _xattn_decode(bp, spec, h, cache, tape, pfx):
     K, G = spec.num_kv_heads, spec.num_heads // spec.num_kv_heads
     qg = q.reshape(B, 1, K, G, spec.head_dim)
     s = jnp.einsum("bqkgh,bskh->bkgqs", qg, k,
+                   precision=MATMUL_PRECISION,
                    preferred_element_type=jnp.float32)
     s = s / jnp.sqrt(jnp.float32(spec.head_dim))
     p = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
     o = jnp.einsum("bkgqs,bskh->bqkgh", p, v.astype(jnp.float32),
+                   precision=MATMUL_PRECISION,
                    preferred_element_type=jnp.float32)
     o = o.reshape(B, 1, spec.q_dim).astype(h.dtype)
     y = tape.dot(f"{pfx}/wo", o, bp["wo"])
@@ -721,9 +732,11 @@ def loss_fn(cfg, policy, params, batch, scales, sinks,
         xch, lch = xs
         if tied:
             logits = jnp.einsum("bsd,vd->bsv", xch, w.astype(xch.dtype),
+                                precision=MATMUL_PRECISION,
                                 preferred_element_type=jnp.float32)
         else:
             logits = jnp.einsum("bsd,dv->bsv", xch, w.astype(xch.dtype),
+                                precision=MATMUL_PRECISION,
                                 preferred_element_type=jnp.float32)
         from repro.core.quant import q_stats, qbound
         logits = qbound(logits, fmt, fmt, scales.get("a:head/logits", 0.0),

@@ -190,7 +190,9 @@ class ServeEngine:
     ``dist.cp_decode`` — the decode KV window over ``data`` (CP, exact
     log-sum-exp merge).  Parameters stay replicated and the attention
     output is gathered before the ``wo`` contraction, so the sharded
-    engine's greedy token streams are bit-identical to single-device.
+    engine's greedy token streams are bit-identical to single-device
+    with interpret-mode kernels; on a TPU its logits agree within
+    rounding (``chip_smoke.py --four-chips`` states the tolerance).
     Incoherent requests (active ``dist`` without its mesh, CP over a
     paged arena, a window CP doesn't divide) raise
     :class:`repro.dist.MeshConfigError` at construction.
@@ -337,11 +339,16 @@ class ServeEngine:
             # params/exps/sinks stay REPLICATED: every contraction that
             # could reorder partial sums runs identically on all devices,
             # which is what keeps sharded greedy streams bit-identical
+            # under interpret-mode kernels
             rep = jax.sharding.NamedSharding(mesh,
                                              jax.sharding.PartitionSpec())
             self.params = jax.device_put(self.params, rep)
             self.exps = jax.device_put(self.exps, rep)
             self.sinks = jax.device_put(self.sinks, rep)
+        # the weights ride into every jit as an argument: a closed-over
+        # array would be baked into the program as a constant (GBs of
+        # HLO for a full-width model)
+        self._w = (self.params, self.exps, self.sinks)
         if self._paged:
             self._alloc = paged.PageAllocator(kvp.total_pages,
                                               self.page_size, kvp.nblocks)
@@ -417,13 +424,13 @@ class ServeEngine:
         if self.prefill_chunk:
             # ONE compile for any prompt length / slot: chunk shape is
             # static, slot index / start / valid count are traced
-            self._chunk = jax.jit(self._chunk_impl, donate_argnums=(0,))
+            self._chunk = jax.jit(self._chunk_impl, donate_argnums=(1,))
             self._seed_keys = jax.jit(kv_pool.seed_slot_keys,
                                       donate_argnums=(0,))
             self._decode = jax.jit(self._decode_masked_impl,
-                                   donate_argnums=(0,))
+                                   donate_argnums=(1,))
         else:
-            self._decode = jax.jit(self._decode_impl, donate_argnums=(0,))
+            self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._slot_tot = jax.jit(kv_pool.slot_totals)
         # MoE prefill routes with a capacity computed over the whole batch,
         # so batching prompts would couple their routing — admit one at a
@@ -441,11 +448,11 @@ class ServeEngine:
             return pool
         return jax.lax.with_sharding_constraint(pool, self._pool_shardings)
 
-    def _prefill_impl(self, tokens, keys):
-        logits, _, cache = T.prefill(self.cfg, self.policy, self.params,
-                                     {"tokens": tokens}, self.exps,
-                                     self.sinks, self.dist,
-                                     max_cache_len=self.max_len)
+    def _prefill_impl(self, w, tokens, keys):
+        params, exps, sinks = w
+        logits, _, cache = T.prefill(self.cfg, self.policy, params,
+                                     {"tokens": tokens}, exps, sinks,
+                                     self.dist, max_cache_len=self.max_len)
         # first generated token sits at absolute position L = prompt length
         pos = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
         safe, bad = sampler.guard_logits(logits)
@@ -465,39 +472,39 @@ class ServeEngine:
                              self.sampler_cfg)
         return nxt, bad
 
-    def _decode_impl(self, pool, tok, pos, keys, nan_mask):
-        logits, _, pool = T.decode_step(self.cfg, self.policy, self.params,
-                                        pool, tok, pos, self.exps,
-                                        self.sinks, self.dist,
-                                        kv_codec=self.codec)
+    def _decode_impl(self, w, pool, tok, pos, keys, nan_mask):
+        params, exps, sinks = w
+        logits, _, pool = T.decode_step(self.cfg, self.policy, params,
+                                        pool, tok, pos, exps, sinks,
+                                        self.dist, kv_codec=self.codec)
         nxt, bad = self._sample_guarded(logits, pos, keys, nan_mask)
         rate = kv_pool.slot_overflow_rates(pool, self.max_slots)
         return nxt, bad, rate, self._constrain_pool(pool)
 
-    def _decode_masked_impl(self, pool, tok, pos, keys, mask, nan_mask):
+    def _decode_masked_impl(self, w, pool, tok, pos, keys, mask, nan_mask):
         # chunked mode: slots mid-prefill (or free) decode garbage whose
         # cache append must be dropped — their pool rows and controller
         # state must stay byte-identical to a solo run
-        logits, _, pool = T.decode_step(self.cfg, self.policy, self.params,
-                                        pool, tok, pos, self.exps,
-                                        self.sinks, self.dist,
-                                        kv_codec=self.codec,
+        params, exps, sinks = w
+        logits, _, pool = T.decode_step(self.cfg, self.policy, params,
+                                        pool, tok, pos, exps, sinks,
+                                        self.dist, kv_codec=self.codec,
                                         append_mask=mask)
         nxt, bad = self._sample_guarded(logits, pos, keys, nan_mask)
         rate = kv_pool.slot_overflow_rates(pool, self.max_slots)
         return nxt, bad, rate, self._constrain_pool(pool)
 
-    def _chunk_impl(self, pool, tokens, slot, p0, n_valid, keys):
+    def _chunk_impl(self, w, pool, tokens, slot, p0, n_valid, keys):
         """One prefill chunk for one slot. ``tokens``: [1, C] (padded);
         ``slot``/``p0``/``n_valid``: traced scalars; ``keys``: [1, 2]."""
         # paged-aware slicing: slot-indexed leaves narrow to B=1, page
         # arenas pass through whole (the chunk scatters into its own
         # slot's pages); reduces to the plain tree_map for slot-major
         sub = paged.slice_slot(pool, slot)
+        params, exps, sinks = w
         logits, _, sub = T.prefill_chunk_step(
-            self.cfg, self.policy, self.params, sub, tokens, p0[None],
-            n_valid[None], self.exps, self.sinks, self.dist,
-            kv_codec=self.codec)
+            self.cfg, self.policy, params, sub, tokens, p0[None],
+            n_valid[None], exps, sinks, self.dist, kv_codec=self.codec)
         pool = self._constrain_pool(paged.merge_slot(pool, sub, slot))
         # the first generated token sits at absolute position p0 + n_valid
         # (== prompt length when this is the final chunk) — the same key
@@ -746,7 +753,7 @@ class ServeEngine:
             tokens = jnp.asarray(np.stack([r.tokens for r in group]))
             keys = jnp.stack([sampler.request_key(self.seed, r.uid)
                               for r in group])
-            first, bad, entry = self._prefill(tokens, keys)
+            first, bad, entry = self._prefill(self._w, tokens, keys)
             self._pool = self._insert(self._pool, entry,
                                       jnp.asarray(slots, jnp.int32), keys)
             first = np.asarray(first)
@@ -838,7 +845,7 @@ class ServeEngine:
         if self._paged and not self._ensure_blocks_safe(s, f, n):
             return                    # requester quarantined (no victim)
         first, bad, self._pool = self._chunk(
-            self._pool, jnp.asarray(toks), jnp.int32(s), jnp.int32(f),
+            self._w, self._pool, jnp.asarray(toks), jnp.int32(s), jnp.int32(f),
             jnp.int32(n), jnp.asarray(self._keys[s:s + 1]))
         self._pfill[s] = f + n
         self._pos[s] = f + n          # frontier (RoPE-safe while masked)
@@ -917,12 +924,12 @@ class ServeEngine:
                 tr.begin("decode_step", n_active=int(self._active.sum()))
             if self.prefill_chunk:
                 nxt, bad, rate, self._pool = self._decode(
-                    self._pool, jnp.asarray(self._tok),
+                    self._w, self._pool, jnp.asarray(self._tok),
                     jnp.asarray(self._pos), jnp.asarray(self._keys),
                     jnp.asarray(self._active), jnp.asarray(nan_mask))
             else:
                 nxt, bad, rate, self._pool = self._decode(
-                    self._pool, jnp.asarray(self._tok),
+                    self._w, self._pool, jnp.asarray(self._tok),
                     jnp.asarray(self._pos), jnp.asarray(self._keys),
                     jnp.asarray(nan_mask))
             nxt, bad, rate = (np.asarray(nxt), np.asarray(bad),

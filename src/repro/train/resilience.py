@@ -33,6 +33,7 @@ import dataclasses
 import enum
 import json
 import os
+import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -64,10 +65,12 @@ class StepRecord:
     flags: int                  # sentinel bitmask (step.FLAG_*)
     loss: float
     info: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    seconds: float = 0.0        # host wall time of the device step
 
     def to_json(self) -> dict:
         return {"cursor": self.cursor, "outcome": self.outcome.value,
-                "flags": self.flags, "loss": self.loss, **self.info}
+                "flags": self.flags, "loss": self.loss,
+                "seconds": self.seconds, **self.info}
 
 
 def flag_names(flags: int) -> List[str]:
@@ -246,10 +249,12 @@ class TrainSupervisor:
                 if self.tracer is not None else None)
         if span is not None:
             span.__enter__()
+        t0 = time.perf_counter()
         new_state, metrics, new_ef = self._step_fn(
             self.state, batch, rng, self.ef, inj)
         flags = int(np.asarray(metrics["flags"]))   # the one extra fetch
         loss = float(np.asarray(metrics["loss"]))
+        seconds = time.perf_counter() - t0          # fetches waited on it
         if span is not None:
             span.__exit__(None, None, None)
 
@@ -281,6 +286,7 @@ class TrainSupervisor:
         if self.tracer is not None and rec.outcome is not StepOutcome.OK:
             self.tracer.instant(f"train:{rec.outcome.value}", tid="train",
                                 cursor=cursor, flags=flags)
+        rec.seconds = seconds
         return rec
 
     def _rollback(self, rec: StepRecord) -> StepRecord:
@@ -401,8 +407,6 @@ class TrainSupervisor:
             return
         if len(self.losses) % self.numerics_every:
             return
-        import time
-
         from repro.obs import train_records
         tap = jax.device_get(metrics["numerics"])
         for rec in train_records(tap["prev_exps"], tap["exps"], tap["acc"],

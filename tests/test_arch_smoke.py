@@ -61,6 +61,26 @@ def test_arch_smoke_train_step(arch):
         assert bool(jnp.all(jnp.isfinite(leaf))), f"{arch}: non-finite param"
 
 
+@pytest.mark.parametrize("fused", [False, True], ids=["composite", "fused"])
+@pytest.mark.parametrize("arch", configs.ARCHS)
+def test_arch_dots_run_at_full_precision(arch, fused,
+                                         assert_dots_full_precision):
+    """Every dot of the DFXP loss and its gradient — model einsums,
+    the tape's matmuls or the fused qmatmul kernels — keeps f32 products."""
+    cfg = configs.get_smoke(arch)
+    key = jax.random.PRNGKey(0)
+    params = T.init_params(cfg, key)
+    gs = T.group_shapes(cfg)
+    pol = PrecisionPolicy("dfxp", fused_matmul=fused)
+    from repro.core import ScaleState
+    exps = ScaleState.create(gs, -6.0).exps
+    sinks = {n: jnp.zeros(s + (3,), jnp.float32) for n, s in gs.items()
+             if n.startswith("g:")}
+    assert_dots_full_precision(
+        jax.grad(lambda p, b: T.loss_fn(cfg, pol, p, b, exps, sinks)[0]),
+        params, _batch(cfg, key))
+
+
 @pytest.mark.parametrize("arch", configs.ARCHS)
 def test_arch_smoke_forward_shapes(arch):
     cfg = configs.get_smoke(arch)
@@ -101,3 +121,25 @@ def test_arch_smoke_decode(arch):
                                       st.exps, sinks)
     assert logits.shape == (B, cfg.vocab_size)
     assert bool(jnp.all(jnp.isfinite(logits)))
+
+
+def test_ssm_long_chunk_grads_finite():
+    """At a published chunk length (mamba2_370m: 256) the cumulative
+    log-decay spans hundreds of nats, so ``exp`` above the causal
+    diagonal overflows; masking it after the exp made every gradient
+    NaN while the loss stayed finite."""
+    from repro.core.tape import QTape
+    from repro.models.ssm import SSMSpec, init_ssm, ssm_forward
+
+    spec = SSMSpec(d_model=64, state=16, headdim=32, chunk=256)
+    params = init_ssm(jax.random.PRNGKey(0), spec)
+    u = jax.random.normal(jax.random.PRNGKey(1), (1, 256, spec.d_model))
+    tape = QTape(PrecisionPolicy("float32"), {}, {})
+
+    def loss(p):
+        return jnp.sum(ssm_forward(p, spec, u, tape, "ssm")[0] ** 2)
+
+    val, grads = jax.jit(jax.value_and_grad(loss))(params)
+    assert bool(jnp.isfinite(val))
+    for name, g in grads.items():
+        assert bool(jnp.all(jnp.isfinite(g))), name

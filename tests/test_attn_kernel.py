@@ -125,12 +125,47 @@ def test_split_k_fully_masked_block():
     assert np.all(np.isfinite(out))
 
 
+@pytest.mark.parametrize("kernel", ["decode", "prefill", "decode_paged",
+                                    "prefill_paged"])
+def test_split_kernels_run_dots_at_full_precision(
+        kernel, assert_dots_full_precision):
+    """The compiled-path kernel bodies keep f32 products: one bf16 MXU
+    pass would be off by ~2e-3 of the output on a TPU."""
+    from repro.kernels.attn import ops as A
+    B, K, G, hd, W, C, P, nb = 2, 2, 2, 8, 24, 4, 8, 3
+    z = jnp.zeros
+    q, qc = z((B, K, G, hd)), z((B, C, K, G, hd))
+    new = z((B, C, K, hd))
+    e = z((B,))
+    idx = jnp.zeros((B,), jnp.int32)
+    kw = dict(width=8, scale=0.5, interpret=True)
+    pool, pages = z((B, W, K, hd), jnp.int8), z((1 + B * nb, P, K, hd),
+                                                jnp.int8)
+    bt = jnp.ones((B, nb), jnp.int32)
+    pos, ppos = z((B, W), jnp.int32), z((B, nb * P), jnp.int32)
+    pe = z((1 + B * nb,))
+    fn = {
+        "decode": lambda: A.flash_decode(q, pool, pool, pos, idx, e, e,
+                                         block_w=8, **kw),
+        "prefill": lambda: A.flash_prefill(qc, new, new, pool, pool, pos,
+                                           idx, idx + C, e, e, block_w=8,
+                                           **kw),
+        "decode_paged": lambda: A.flash_decode_paged(
+            q, pages, pages, bt, ppos, idx, pe, pe, force_split=True, **kw),
+        "prefill_paged": lambda: A.flash_prefill_paged(
+            qc, new, new, pages, pages, bt, ppos, idx, idx + C, pe, pe,
+            force_split=True, **kw),
+    }[kernel]
+    assert_dots_full_precision(fn)
+
+
 # ---------------------------------------------------------------------------
 # dispatch: split selection + persisted autotune table
 # ---------------------------------------------------------------------------
 
 def test_attn_blocks_interpret_is_whole_window():
-    assert dispatch.attn_blocks_for(300, 4, 64, width=8, interpret=True) == 300
+    assert dispatch.attn_blocks_for(300, 8, 4, 64, width=8,
+                                    interpret=True) == 300
 
 
 def test_autotune_persistence_roundtrip(tmp_path):
@@ -141,7 +176,7 @@ def test_autotune_persistence_roundtrip(tmp_path):
     try:
         dispatch.reset_autotune()
         dispatch._BLOCK_CACHE[("nn", 256, 256, 512)] = (128, 128, 256)
-        dispatch._BLOCK_CACHE[("attn", 4096, 4, 64, 8)] = (512,)
+        dispatch._BLOCK_CACHE[("attn", 4096, 8, 4, 64, 8)] = (512,)
         dispatch._MEASURED.update(dispatch._BLOCK_CACHE)
         dispatch._BLOCK_CACHE[("nt", 64, 64, 64)] = (64, 64, 64)  # heuristic
         assert dispatch.save_autotune(path) == path
@@ -154,7 +189,7 @@ def test_autotune_persistence_roundtrip(tmp_path):
                                    interpret=False) == (128, 128, 256)
         # and the attn bucket resolves to the persisted split
         dispatch.set_autotune(measure=False)
-        assert dispatch.attn_blocks_for(4000, 4, 64, width=8,
+        assert dispatch.attn_blocks_for(4000, 8, 4, 64, width=8,
                                         interpret=False) == 512
     finally:
         dispatch.reset_autotune()
@@ -186,14 +221,14 @@ def test_autotune_save_merges_and_load_validates(tmp_path):
         dispatch._MEASURED.add(("nn", 256, 256, 512))
         dispatch.save_autotune(path)
         dispatch.reset_autotune()          # "process B" measures another
-        dispatch._BLOCK_CACHE[("attn", 4096, 4, 64, 8)] = (512,)
-        dispatch._MEASURED.add(("attn", 4096, 4, 64, 8))
+        dispatch._BLOCK_CACHE[("attn", 4096, 8, 4, 64, 8)] = (512,)
+        dispatch._MEASURED.add(("attn", 4096, 8, 4, 64, 8))
         dispatch.save_autotune(path)
         dispatch.reset_autotune()
         assert dispatch.load_autotune(path) == 2   # both survived
         # zero blocks / over-budget split / wrong arity / unknown kind
         json.dump({"nn|256|256|512": [0, 0, 0],
-                   "attn|4096|4|64|8": [1 << 20],
+                   "attn|4096|8|4|64|8": [1 << 20],
                    "nt|64|64": [64, 64, 64],
                    "bogus|1": [1]}, open(path, "w"))
         dispatch.reset_autotune()

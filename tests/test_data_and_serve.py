@@ -78,3 +78,27 @@ def test_serve_cli_constructs_serve_engine(capsys):
     out = capsys.readouterr().out
     assert "served 2 requests" in out
     assert "tok/s" in out
+
+
+def test_compile_cache_placement(monkeypatch):
+    """The entry points' cache helper: JAX_COMPILATION_CACHE_DIR wins and
+    nothing else is set; otherwise a fixed <checkout>/.cache/jax, the same
+    .cache/ the autotune table defaults to."""
+    import pathlib
+
+    from repro import compile_cache
+    from repro.kernels import dispatch
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax")
+        assert compile_cache.use_compile_cache() == "/elsewhere/jax"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.use_compile_cache()
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert path == str(root / ".cache" / "jax")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert dispatch._CACHE_DEFAULT == str(root / ".cache"
+                                              / "autotune.json")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -76,6 +76,19 @@ def test_qmatmul_widths(width):
                                rtol=1e-6, atol=1e-5)
 
 
+@pytest.mark.parametrize("kind", ["nn", "nt", "tn"])
+def test_qmatmul_kernel_dot_at_full_precision(kind,
+                                              assert_dots_full_precision):
+    """The kernel's dot keeps f32 products: one bf16 MXU pass would round
+    10-bit operands to 8 significant bits on a TPU."""
+    from repro.kernels.qmatmul.ops import qmm
+    a = jnp.zeros((128, 128), jnp.float32)
+    assert_dots_full_precision(lambda: qmm(a, a, 0.0, 0.0, kind=kind,
+                                           width_a=10, width_b=10,
+                                           blocks=(128, 128, 128),
+                                           interpret=True))
+
+
 def test_qmatmul_quantization_actually_applied():
     # identity scales wide enough that quantization is a no-op vs exact matmul
     a = jnp.round(jax.random.normal(jax.random.PRNGKey(5), (64, 128)) * 4)

@@ -509,7 +509,7 @@ def test_dispatch_profile_records_and_renders():
         for _ in range(3):
             blocks = dispatch.blocks_for("fwd", 8, 16, 32, interpret=True)
         assert blocks == (8, 16, 32)
-        w = dispatch.attn_blocks_for(64, 4, 8, interpret=True)
+        w = dispatch.attn_blocks_for(64, 2, 4, 8, interpret=True)
         assert w == 64
         stats = dispatch.profile_stats()
         mm = stats[("mm", "fwd", "interp")]

@@ -61,16 +61,21 @@ def test_f32_engine_matches_lockstep_bitwise(model, prompts, f32_eng):
     np.testing.assert_array_equal(np.stack(out), ref)
 
 
-def test_packed_cache_matches_f32_greedy(model, prompts, f32_eng):
-    """int8/int16 packed-pool greedy == f32-pool greedy for >= 8 steps."""
+def test_packed_cache_matches_f32_greedy(model, prompts, f32_eng,
+                                        assert_near_f32_greedy):
+    """Packed-pool greedy over 8 steps: int16 == f32-pool greedy exactly;
+    int8 picks, at every step, a token within the stated logit tolerance
+    of the float32 model's best (its rounding may fork a near-tie)."""
     cfg, params = model
     ref, _ = _wave(f32_eng, [(p, 8) for p in prompts])
     for bits in (8, 16):
         eng = ServeEngine(cfg, POL, params, max_slots=2, max_len=24,
                           options=EngineOptions(cache_bits=bits))
         out, _ = _wave(eng, [(p, 8) for p in prompts])
-        for o, r in zip(out, ref):
-            np.testing.assert_array_equal(o, r)
+        for p, o, r in zip(prompts, out, ref):
+            if bits == 16:
+                np.testing.assert_array_equal(o, r)
+            assert_near_f32_greedy(cfg, params, p, o)
         # every decode append on both slots was quantized and accounted
         assert eng.cache_stats()["cache_appends_quantized"] > 0
 
