@@ -161,9 +161,8 @@ def main(argv=None):
     if args.numerics_log:
         from repro.obs import NumericsLog
         num_log = NumericsLog(args.numerics_log)
-    from repro.obs import MetricsRegistry, Tracer
-    tracer = Tracer()
-    metrics = MetricsRegistry()
+    from repro.obs import Tracer
+    tracer = Tracer()      # the HALTED bundle's trace.json
 
     # --- fault harness ------------------------------------------------------
     faults = []
@@ -174,8 +173,8 @@ def main(argv=None):
               f"{[type(f).__name__ for f in faults]}")
     if args.kill_at:
         faults.append(Kill(step=args.kill_at))
-    harness = (FaultHarness(faults, seed=args.chaos or 0, tracer=tracer,
-                            metrics=metrics) if faults else None)
+    harness = (FaultHarness(faults, seed=args.chaos or 0, tracer=tracer)
+               if faults else None)
 
     mgr = (CheckpointManager(args.ckpt_dir, keep=args.keep)
            if args.ckpt_dir else None)
@@ -193,7 +192,7 @@ def main(argv=None):
         runaway_ovf=args.runaway_ovf or None,
         compress_bits=args.grad_compress_bits or None,
         microbatches=args.microbatches,
-        faults=harness, tracer=tracer, metrics=metrics,
+        faults=harness, tracer=tracer,
         numerics_log=num_log, numerics_every=args.numerics_every,
         bundle_dir=bundle_dir)
 

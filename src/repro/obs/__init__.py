@@ -3,7 +3,9 @@
 Three stdlib-only layers threaded through serve, kernels, and train:
 
 * :mod:`repro.obs.trace` — :class:`Tracer` span/instant/counter events →
-  Chrome-trace/Perfetto JSON (``launch.serve --trace-out``);
+  Chrome-trace/Perfetto JSON (``launch.serve --trace-out``), and
+  :class:`span`, the step-phase span that also lands in a
+  ``jax.profiler`` trace as ``repro:<name>``;
 * :mod:`repro.obs.metrics` — :class:`MetricsRegistry` of counters,
   gauges, and log-bucketed histograms, with JSONL snapshots and a
   Prometheus-text endpoint (``--metrics-port``);
@@ -18,10 +20,10 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       start_http_server)
 from .numerics import (NumericsLog, count_moves, read_jsonl, serve_records,
                        train_records)
-from .trace import Tracer, validate_trace
+from .trace import Tracer, span, validate_trace
 
 __all__ = [
-    "Tracer", "validate_trace",
+    "Tracer", "span", "validate_trace",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "start_http_server",
     "NumericsLog", "serve_records", "train_records", "count_moves",
     "read_jsonl",

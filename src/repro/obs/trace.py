@@ -3,12 +3,12 @@
 A :class:`Tracer` is a process-local, dependency-free event recorder the
 serve engine (and anything else) threads its step phases through:
 
-* **spans** — ``with tracer.span("decode_step", n_active=3): ...`` (or the
-  explicit :meth:`begin`/:meth:`end` pair) record a named duration on one
-  track.  Spans nest per track; export writes them as Chrome-trace
-  complete events (``ph: "X"``) whose ``ts``/``dur`` containment encodes
-  the nesting, which both ``chrome://tracing`` and Perfetto render as
-  stacked slices.
+* **spans** — ``with span("decode_step", tracer, n_active=3): ...`` (or
+  the explicit :meth:`Tracer.begin`/:meth:`Tracer.end` pair) record a
+  named duration on one track.  Spans nest per track; export writes them
+  as Chrome-trace complete events (``ph: "X"``) whose ``ts``/``dur``
+  containment encodes the nesting, which both ``chrome://tracing`` and
+  Perfetto render as stacked slices.
 * **instants** — point events (``submit``, ``finish``, ``preempt``,
   fault-harness injections) rendered as markers.
 * **counters** — named numeric series (queue depth, active slots, §5
@@ -18,13 +18,19 @@ serve engine (and anything else) threads its step phases through:
 Everything is host-side and allocation-light (one small dict per event);
 nothing here ever touches a device array.  The zero-cost-when-disabled
 contract lives at the call sites: code holds ``tracer = None`` and guards
-every hook with ``if tracer is not None`` — this module is simply never
-imported on the hot path of an unobserved run.
+every hook with ``if tracer is not None``; a :class:`span` with no tracer
+calls no :class:`Tracer` method.
 
 :func:`export` / :func:`to_chrome` produce the Chrome trace event format
 (``{"traceEvents": [...]}``) sorted so parents precede children —
 loadable directly in ``chrome://tracing`` or https://ui.perfetto.dev.
 :func:`validate_trace` is the schema check CI runs against the artifact.
+
+:class:`span` is the one span the program's step phases open: it always
+opens a ``jax.profiler.TraceAnnotation`` named ``repro:<name>`` (so a
+``jax.profiler`` capture holds the phase on the device trace's clock,
+beside the device's ``XLA Ops``), and records into a :class:`Tracer`
+only when the caller holds one.
 """
 from __future__ import annotations
 
@@ -35,21 +41,62 @@ from typing import Dict, List, Optional
 # Chrome trace event phases this module emits (and the validator accepts).
 _PHASES = {"X", "i", "C", "M"}
 
+# Prefix of every program span in a jax.profiler trace.
+PROFILER_PREFIX = "repro:"
 
-class _SpanCtx:
-    """Context manager closing one span on one track."""
+_annotation = None
 
-    __slots__ = ("_tracer", "_tid")
 
-    def __init__(self, tracer: "Tracer", tid: str):
-        self._tracer = tracer
-        self._tid = tid
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so the rest
+    of this module stays stdlib-only."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
 
-    def __enter__(self):
+
+class span:
+    """``with span("train.wait", tracer, cursor=7) as sp: ...``.
+
+    Opens ``repro:<name>`` in the profiler's host plane (``args`` become
+    the event's stats) and, when ``tracer`` is a :class:`Tracer`, the
+    span ``name`` on track ``tid``.  With no profiler running and no
+    tracer, it costs this object, a ``TraceMe`` and its enter/exit, and
+    calls no :class:`Tracer` method.  ``t0``/``t1`` are the
+    ``time.perf_counter`` reads at entry and exit; :meth:`note` adds
+    arguments known only before the span closes.
+    """
+
+    __slots__ = ("_tracer", "_tid", "_name", "_args", "_ann", "_late",
+                 "t0", "t1")
+
+    def __init__(self, name: str, tracer: Optional["Tracer"] = None,
+                 tid: str = "engine", **args):
+        self._name, self._tracer, self._tid = name, tracer, tid
+        self._args = args if tracer is not None else None
+        self._ann = _trace_annotation()(PROFILER_PREFIX + name, **args)
+        self._late = None
+
+    def __enter__(self) -> "span":
+        if self._tracer is not None:
+            self._tracer.begin(self._name, tid=self._tid, **self._args)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
-    def __exit__(self, *exc):
-        self._tracer.end(tid=self._tid)
+    def note(self, **args) -> None:
+        """Add ``args`` to the open span (profiler event and tracer)."""
+        self._ann.set_metadata(**args)
+        if self._tracer is not None:
+            self._late = {**(self._late or {}), **args}
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        if self._tracer is not None:
+            self._tracer.end(tid=self._tid, **(self._late or {}))
         return False
 
 
@@ -96,11 +143,6 @@ class Tracer:
         if sp["args"]:
             ev["args"] = sp["args"]
         self.events.append(ev)
-
-    def span(self, name: str, tid: str = "engine", **args) -> _SpanCtx:
-        """``with tracer.span("phase"): ...`` — begin/end as a context."""
-        self.begin(name, tid=tid, **args)
-        return _SpanCtx(self, tid)
 
     def instant(self, name: str, tid: str = "engine", **args) -> None:
         ev = {"name": name, "ph": "i", "ts": self.now_us(), "pid": self.pid,
@@ -209,4 +251,4 @@ def validate_trace(obj: dict) -> None:
             open_ends.append(te)
 
 
-__all__ = ["Tracer", "validate_trace"]
+__all__ = ["Tracer", "span", "validate_trace"]

@@ -99,6 +99,7 @@ from repro.core import ScaleState
 from repro.core.policy import PrecisionPolicy
 from repro.dist import DistCtx, MeshConfigError, serve_pod_ctx
 from repro.models import transformer as T
+from repro.obs.trace import span as obs_span
 
 from . import kv_pool, metrics, paged, sampler
 
@@ -633,14 +634,8 @@ class ServeEngine:
         ``max_preempts`` resolves FAILED instead (thrash bound).
         """
         req = self._reqs[victim]
-        if self._tracer is not None:
-            self._tracer.begin("preempt", uid=req.uid, slot=victim,
-                               n_preempt=req.n_preempt)
-            try:
-                self._preempt_impl(victim, req)
-            finally:
-                self._tracer.end()
-        else:
+        with obs_span("preempt", self._tracer, uid=req.uid, slot=victim,
+                      n_preempt=req.n_preempt):
             self._preempt_impl(victim, req)
 
     def _preempt_impl(self, victim: int, req: Request) -> None:
@@ -884,29 +879,16 @@ class ServeEngine:
         if self._faults is not None:
             self._faults.on_step(self)
         self._expire_queue()
-        if self.prefill_chunk:
-            if tr is None:
+        with obs_span("admit", tr, queued=len(self._queue)):
+            if self.prefill_chunk:
                 self._admit_chunked()
+            else:
+                self._admit()
+        if self.prefill_chunk and self._prefilling:
+            s = self._prefilling[0]
+            with obs_span("prefill_chunk", tr, uid=self._reqs[s].uid,
+                          slot=int(s), p0=int(self._pfill[s])):
                 self._step_prefill_chunk()
-            else:
-                tr.begin("admit", queued=len(self._queue))
-                self._admit_chunked()
-                tr.end()
-                if self._prefilling:
-                    s = self._prefilling[0]
-                    tr.begin("prefill_chunk", uid=self._reqs[s].uid,
-                             slot=int(s), p0=int(self._pfill[s]))
-                    try:
-                        self._step_prefill_chunk()
-                    finally:
-                        tr.end()
-        else:
-            if tr is None:
-                self._admit()
-            else:
-                tr.begin("admit", queued=len(self._queue))
-                self._admit()
-                tr.end()
         if self._active.any():
             nan_mask = np.zeros(self.max_slots, bool)
             if self._faults is not None:
@@ -920,41 +902,39 @@ class ServeEngine:
                     if self._active[s]:   # earlier preemption may clear it
                         self._ensure_blocks_safe(s, int(self._pos[s]), 1)
         if self._active.any():
-            if tr is not None:
-                tr.begin("decode_step", n_active=int(self._active.sum()))
-            if self.prefill_chunk:
-                nxt, bad, rate, self._pool = self._decode(
-                    self._w, self._pool, jnp.asarray(self._tok),
-                    jnp.asarray(self._pos), jnp.asarray(self._keys),
-                    jnp.asarray(self._active), jnp.asarray(nan_mask))
-            else:
-                nxt, bad, rate, self._pool = self._decode(
-                    self._w, self._pool, jnp.asarray(self._tok),
-                    jnp.asarray(self._pos), jnp.asarray(self._keys),
-                    jnp.asarray(nan_mask))
-            nxt, bad, rate = (np.asarray(nxt), np.asarray(bad),
-                              np.asarray(rate))
-            self.metrics.on_decode_step()
-            for s in np.where(self._active)[0]:
-                s = int(s)
-                if bad[s]:
-                    # NaN/Inf decode logits: drop the poisoned token,
-                    # quarantine the request, keep siblings untouched
-                    self._finish(s, RequestStatus.FAILED)
-                    continue
-                if self.runaway_ovf is not None and \
-                        rate[s] > self.runaway_ovf:
-                    # §5 overflow runaway: the controller lost the race
-                    self._finish(s, RequestStatus.FAILED)
-                    continue
-                tok = int(nxt[s])
-                self._gen[s].append(tok)
-                self._pos[s] += 1
-                self._tok[s] = tok
-                self.metrics.on_token(self._reqs[s].uid)
-                self._maybe_finish(s, tok)
-            if tr is not None:
-                tr.end()
+            with obs_span("decode_step", tr,
+                          n_active=int(self._active.sum())):
+                if self.prefill_chunk:
+                    nxt, bad, rate, self._pool = self._decode(
+                        self._w, self._pool, jnp.asarray(self._tok),
+                        jnp.asarray(self._pos), jnp.asarray(self._keys),
+                        jnp.asarray(self._active), jnp.asarray(nan_mask))
+                else:
+                    nxt, bad, rate, self._pool = self._decode(
+                        self._w, self._pool, jnp.asarray(self._tok),
+                        jnp.asarray(self._pos), jnp.asarray(self._keys),
+                        jnp.asarray(nan_mask))
+                nxt, bad, rate = (np.asarray(nxt), np.asarray(bad),
+                                  np.asarray(rate))
+                self.metrics.on_decode_step()
+                for s in np.where(self._active)[0]:
+                    s = int(s)
+                    if bad[s]:
+                        # NaN/Inf decode logits: drop the poisoned token,
+                        # quarantine the request, keep siblings untouched
+                        self._finish(s, RequestStatus.FAILED)
+                        continue
+                    if self.runaway_ovf is not None and \
+                            rate[s] > self.runaway_ovf:
+                        # §5 overflow runaway: the controller lost the race
+                        self._finish(s, RequestStatus.FAILED)
+                        continue
+                    tok = int(nxt[s])
+                    self._gen[s].append(tok)
+                    self._pos[s] += 1
+                    self._tok[s] = tok
+                    self.metrics.on_token(self._reqs[s].uid)
+                    self._maybe_finish(s, tok)
         self._expire_inflight()
         if tr is not None:
             tr.counter("queue", {"queue_depth": len(self._queue),

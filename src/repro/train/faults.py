@@ -30,7 +30,7 @@ real boundary the matching production fault would cross:
 
 :class:`FaultHarness` fires each fault exactly once (or for its
 ``count`` window), keeps a JSON-able event log, and mirrors every event
-into the PR 8 tracer/metrics registry when attached.  Injectors no-op
+onto a :class:`repro.obs.Tracer` when attached.  Injectors no-op
 with a logged reason when their precondition fails, so a chaos sweep
 never crashes the harness itself.  :func:`chaos_plan` draws a
 reproducible fault mix from a seed.
@@ -123,20 +123,16 @@ class FaultHarness:
     pending faults.  ``log`` accumulates one JSON-able dict per event.
     """
 
-    def __init__(self, faults, seed: int = 0, tracer=None, metrics=None):
+    def __init__(self, faults, seed: int = 0, tracer=None):
         self.faults = list(faults)
         self.seed = seed
         self.log: List[dict] = []
         self.tracer = tracer
-        self._c_injected = (metrics.counter("train_faults_injected")
-                            if metrics is not None else None)
 
     def _event(self, kind: str, **kw) -> None:
         self.log.append({"kind": kind, **kw})
         if self.tracer is not None:
             self.tracer.instant(f"fault:{kind}", tid="faults", **kw)
-        if self._c_injected is not None and not kind.endswith("_skipped"):
-            self._c_injected.inc()
 
     def log_supervisor_event(self, kind: str, **kw) -> None:
         """Supervisor outcomes land in the same log (rollbacks, halts),

@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.checkpoint import CheckpointError, CheckpointManager
 from repro.core.policy import PrecisionPolicy
+from repro.obs.trace import span as obs_span
 from repro.optim.opt import OptConfig
 
 from .state import TrainState
@@ -65,7 +66,7 @@ class StepRecord:
     flags: int                  # sentinel bitmask (step.FLAG_*)
     loss: float
     info: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    seconds: float = 0.0        # host wall time of the device step
+    seconds: float = 0.0        # host time of launch and fetches
 
     def to_json(self) -> dict:
         return {"cursor": self.cursor, "outcome": self.outcome.value,
@@ -105,7 +106,8 @@ class TrainSupervisor:
       residual buffers become part of the checkpointed state.
     * ``faults`` — a :class:`repro.train.faults.FaultHarness`.
     * ``tracer``/``metrics``/``numerics_log`` — repro.obs hooks; all
-      optional and zero-cost when absent.
+      optional and zero-cost when absent.  ``metrics`` holds the
+      ``train_host_fetches`` counter.
     * ``bundle_dir`` — where the HALTED diagnostic bundle lands.
     """
 
@@ -166,11 +168,8 @@ class TrainSupervisor:
             from repro.obs import MetricsRegistry
             metrics = MetricsRegistry()
         self.metrics = metrics
-        self._c = {o: metrics.counter(f"train_steps_{o.value}")
-                   for o in StepOutcome}
-        self._c_ckpt = metrics.counter("train_ckpt_commits")
-        self._c_ckpt_err = metrics.counter("train_ckpt_errors")
-        self._c_rollback_fail = metrics.counter("train_rollback_failures")
+        self._c_fetch = metrics.counter(
+            "train_host_fetches", "device-to-host fetches of the supervisor")
 
     # -- checkpoint tree ---------------------------------------------------
     def ckpt_tree(self) -> dict:
@@ -211,54 +210,73 @@ class TrainSupervisor:
 
     def commit(self, *, sync: bool = True) -> bool:
         """Write a checkpoint now.  Never raises: a failed write logs an
-        event, bumps ``train_ckpt_errors``, and returns False."""
+        event and returns False."""
         if self.manager is None:
             return False
         try:
             self.manager.wait()
         except Exception as e:               # surfaced background failure
-            self._c_ckpt_err.inc()
             self._event("ckpt_async_error", error=str(e))
         try:
+            self._c_fetch.inc()     # the manager fetches the tree to host
             if sync:
                 self.manager.save(self.cursor, self.ckpt_tree())
             else:
                 self.manager.save_async(self.cursor, self.ckpt_tree())
         except Exception as e:
-            self._c_ckpt_err.inc()
             self._event("ckpt_write_error", cursor=self.cursor,
                         error=str(e))
             return False
         self._last_commit = self.cursor
-        self._c_ckpt.inc()
         return True
 
     # -- the supervised step ----------------------------------------------
     def step_once(self) -> StepRecord:
-        """One supervised step attempt; resolves to a StepRecord."""
+        """One supervised step attempt; resolves to a StepRecord.
+
+        Its phases are :class:`repro.obs.span`s, each with the data
+        cursor as its argument: ``train.step`` holds ``train.batch``
+        (host rows and their transfer), ``train.launch`` (the step
+        program dispatched), ``train.wait`` (the flags and loss fetches,
+        the host blocked on the device) and ``train.record`` (outcome
+        bookkeeping, numerics, checkpoint).  ``train.step`` ends noting
+        ``fetches``, this attempt's device-to-host fetches.
+        """
         if self.halted:
             raise RuntimeError("supervisor is HALTED; inspect the bundle "
                                f"at {self.bundle_dir!r}")
-        if self.faults is not None:
-            self.faults.on_step(self)
-        inj = (self.faults.injection(self) if self.faults is not None
-               else benign_injection())
-        batch = self.batch_fn(self.cursor)
-        rng = jax.random.fold_in(self.rng, self.cursor)
-        span = (self.tracer.span("train_step", tid="train")
-                if self.tracer is not None else None)
-        if span is not None:
-            span.__enter__()
-        t0 = time.perf_counter()
-        new_state, metrics, new_ef = self._step_fn(
-            self.state, batch, rng, self.ef, inj)
-        flags = int(np.asarray(metrics["flags"]))   # the one extra fetch
-        loss = float(np.asarray(metrics["loss"]))
-        seconds = time.perf_counter() - t0          # fetches waited on it
-        if span is not None:
-            span.__exit__(None, None, None)
+        cursor, tr = self.cursor, self.tracer
+        fetched = self._c_fetch.value
+        with obs_span("train.step", tr, "train", cursor=cursor) as step:
+            if self.faults is not None:
+                self.faults.on_step(self)
+            inj = (self.faults.injection(self) if self.faults is not None
+                   else benign_injection())
+            with obs_span("train.batch", tr, "train", cursor=cursor):
+                batch = self.batch_fn(cursor)
+            with obs_span("train.launch", tr, "train",
+                          cursor=cursor) as launch:
+                rng = jax.random.fold_in(self.rng, cursor)
+                new_state, metrics, new_ef = self._step_fn(
+                    self.state, batch, rng, self.ef, inj)
+            with obs_span("train.wait", tr, "train", cursor=cursor) as wait:
+                flags = int(np.asarray(metrics["flags"]))  # one extra fetch
+                loss = float(np.asarray(metrics["loss"]))
+                self._c_fetch.inc(2)
+            with obs_span("train.record", tr, "train", cursor=cursor):
+                rec = self._record(cursor, flags, loss, metrics,
+                                   new_state, new_ef)
+            rec.seconds = wait.t1 - launch.t0   # launch and the fetches
+            step.note(fetches=int(self._c_fetch.value - fetched))
+        if rec.outcome is StepOutcome.HALTED:
+            # after the spans close: the bundle's trace holds this attempt
+            bundle = self.write_bundle()
+            self._event("halted", cursor=rec.cursor, bundle=bundle)
+        return rec
 
-        cursor = self.cursor
+    def _record(self, cursor: int, flags: int, loss: float, metrics,
+                new_state, new_ef) -> StepRecord:
+        """Resolve the attempt at ``cursor`` from its sentinel flags."""
         self.cursor += 1
         self.state, self.ef = new_state, new_ef     # select ran on device
         if flags == 0:
@@ -279,14 +297,9 @@ class TrainSupervisor:
             if self._consec_skips > self.skip_budget:
                 rec = self._rollback(rec)
         self.outcomes.append(rec)
-        self._c[rec.outcome].inc()
-        if rec.outcome is StepOutcome.HALTED:
-            bundle = self.write_bundle()
-            self._event("halted", cursor=rec.cursor, bundle=bundle)
         if self.tracer is not None and rec.outcome is not StepOutcome.OK:
             self.tracer.instant(f"train:{rec.outcome.value}", tid="train",
                                 cursor=cursor, flags=flags)
-        rec.seconds = seconds
         return rec
 
     def _rollback(self, rec: StepRecord) -> StepRecord:
@@ -311,7 +324,6 @@ class TrainSupervisor:
                 self._event("rollback_restore_failed", error=str(e))
         if restored is None:
             self._rollback_failures += 1
-            self._c_rollback_fail.inc()
             if self._rollback_failures >= 2:
                 self.halted = True
                 # bundle is written by step_once AFTER this record lands
@@ -409,6 +421,7 @@ class TrainSupervisor:
             return
         from repro.obs import train_records
         tap = jax.device_get(metrics["numerics"])
+        self._c_fetch.inc()
         for rec in train_records(tap["prev_exps"], tap["exps"], tap["acc"],
                                  step=int(self.state.step),
                                  t=time.perf_counter()):

@@ -18,6 +18,12 @@ Order of operations per step (all inside one jit program):
 In ``packed`` storage mode, parameters/momentum live as int-mantissa
 ``PackedArray``s; step 4 unpacks per-leaf (elementwise, fuses) and step 5
 re-packs, so wide master copies never persist in HBM.
+
+The phases run under ``jax.named_scope``s, which reach each compiled
+instruction's ``op_name`` metadata (and a profiler's view of it) and
+rename no instruction: ``fwd_bwd`` (1), ``grad_quant`` (2-3),
+``optimizer`` (4 and the max-norm of 6), ``dfxp_store`` (5),
+``controller`` (7) and, supervised, ``sentinels``.
 """
 from __future__ import annotations
 
@@ -168,7 +174,9 @@ def make_train_step(
                 loss = loss * inj["loss_scale"]
             return loss, st
 
-        grad_fn = jax.value_and_grad(loss_wrap, argnums=(0, 1), has_aux=True)
+        # the forward and backward pass, traced inside the call
+        grad_fn = jax.named_scope("fwd_bwd")(jax.value_and_grad(
+            loss_wrap, argnums=(0, 1), has_aux=True))
 
         if microbatches > 1:
             for key in ("labels", "y", "tokens", "x"):
@@ -212,95 +220,102 @@ def make_train_step(
             (loss, fwd_stats), (grads, sink_stats) = grad_fn(params_c, sinks,
                                                              batch)
 
-        if inj is not None:
-            poison = jnp.where(inj["grad_nan"], jnp.float32(jnp.nan),
-                               jnp.float32(0.0))
-            grads = jax.tree.map(lambda g: g + poison.astype(g.dtype), grads)
-
-        if grad_transform is not None:
-            grads = grad_transform(grads)
-
-        new_ef = ef
-        if ef_transform is not None:
-            grads, new_ef = ef_transform(grads, ef)
-
         # ---- gradient processing ------------------------------------------
-        gnorm = global_norm(grads)
-        if opt_cfg.grad_clip:
-            grads, _ = clip_by_global_norm(grads, opt_cfg.grad_clip)
+        with jax.named_scope("grad_quant"):
+            if inj is not None:
+                poison = jnp.where(inj["grad_nan"], jnp.float32(jnp.nan),
+                                   jnp.float32(0.0))
+                grads = jax.tree.map(lambda g: g + poison.astype(g.dtype),
+                                     grads)
 
-        all_stats: Dict[str, Array] = {}
-        for d in (fwd_stats, sink_stats):
-            for k, v in d.items():
-                key = k if not k.startswith("g:") else k
-                all_stats[key] = all_stats.get(key, 0) + v
+            if grad_transform is not None:
+                grads = grad_transform(grads)
 
-        if quant_params:
-            grads, gstats = _map_with_group(
-                lambda g, e, n: quantize_param(g, policy.comp_width, e),
-                grads, state.scale.exps, "pg:")
-            all_stats.update(gstats)
+            new_ef = ef
+            if ef_transform is not None:
+                grads, new_ef = ef_transform(grads, ef)
+
+            gnorm = global_norm(grads)
+            if opt_cfg.grad_clip:
+                grads, _ = clip_by_global_norm(grads, opt_cfg.grad_clip)
+
+            all_stats: Dict[str, Array] = {}
+            for d in (fwd_stats, sink_stats):
+                for k, v in d.items():
+                    key = k if not k.startswith("g:") else k
+                    all_stats[key] = all_stats.get(key, 0) + v
+
+            if quant_params:
+                grads, gstats = _map_with_group(
+                    lambda g, e, n: quantize_param(g, policy.comp_width, e),
+                    grads, state.scale.exps, "pg:")
+                all_stats.update(gstats)
 
         # ---- optimizer (wide math) ----------------------------------------
-        if opt_cfg.kind == "sgd":
-            updates, new_opt = sgd_update(opt_cfg, grads, mom_c, state.step)
-        else:
-            updates, new_opt = adamw_update(opt_cfg, grads, mom_c, state.step,
-                                            params=params_c)
+        with jax.named_scope("optimizer"):
+            if opt_cfg.kind == "sgd":
+                updates, new_opt = sgd_update(opt_cfg, grads, mom_c,
+                                              state.step)
+            else:
+                updates, new_opt = adamw_update(opt_cfg, grads, mom_c,
+                                                state.step, params=params_c)
 
-        new_params = jax.tree.map(lambda p, u: (p.astype(jnp.float32)
-                                                + u).astype(jnp.float32),
-                                  params_c, updates)
-        if opt_cfg.max_col_norm:
-            new_params = apply_max_norm(new_params, opt_cfg.max_col_norm)
+            new_params = jax.tree.map(lambda p, u: (p.astype(jnp.float32)
+                                                    + u).astype(jnp.float32),
+                                      params_c, updates)
+            if opt_cfg.max_col_norm:
+                new_params = apply_max_norm(new_params, opt_cfg.max_col_norm)
 
         # ---- parameter/momentum storage quantization ----------------------
-        def q_store(x, e, name, key=None):
-            sk = None
-            if policy.stochastic_rounding:
-                sk = jax.random.fold_in(rng, hash(name) % (2 ** 31))
-            return quantize_param(x, policy.update_width, e,
-                                  stochastic_key=sk)
+        with jax.named_scope("dfxp_store"):
+            def q_store(x, e, name, key=None):
+                sk = None
+                if policy.stochastic_rounding:
+                    sk = jax.random.fold_in(rng, hash(name) % (2 ** 31))
+                return quantize_param(x, policy.update_width, e,
+                                      stochastic_key=sk)
 
-        if quant_params:
-            if policy.storage == "packed":
-                def pk(x, e, name):
-                    y, st = q_store(x, e, name)
-                    return pack(y, policy.update_width, _bexp(e, y)), st
-                new_params, pstats = _map_with_group(
-                    pk, new_params, state.scale.exps, "p:")
-                all_stats.update(pstats)
-                if policy.quantize_momentum and opt_cfg.kind == "sgd":
-                    new_mom, mstats = _map_with_group(
-                        pk, new_opt["momentum"], state.scale.exps, "pm:")
-                    new_opt = {"momentum": new_mom}
-                    all_stats.update(mstats)
-            else:
-                new_params, pstats = _map_with_group(
-                    q_store, new_params, state.scale.exps, "p:")
-                all_stats.update(pstats)
-                if policy.quantize_momentum and opt_cfg.kind == "sgd":
-                    new_mom, mstats = _map_with_group(
-                        q_store, new_opt["momentum"], state.scale.exps, "pm:")
-                    new_opt = {"momentum": new_mom}
-                    all_stats.update(mstats)
-        elif policy.enabled:
-            # float emulation of the storage format (fp16/bf16/fp8 rows)
-            from repro.core.quant import float_round
-            fmt = policy.update_format()
-            new_params = jax.tree.map(lambda x: float_round(x, fmt),
-                                      new_params)
+            if quant_params:
+                if policy.storage == "packed":
+                    def pk(x, e, name):
+                        y, st = q_store(x, e, name)
+                        return pack(y, policy.update_width, _bexp(e, y)), st
+                    new_params, pstats = _map_with_group(
+                        pk, new_params, state.scale.exps, "p:")
+                    all_stats.update(pstats)
+                    if policy.quantize_momentum and opt_cfg.kind == "sgd":
+                        new_mom, mstats = _map_with_group(
+                            pk, new_opt["momentum"], state.scale.exps, "pm:")
+                        new_opt = {"momentum": new_mom}
+                        all_stats.update(mstats)
+                else:
+                    new_params, pstats = _map_with_group(
+                        q_store, new_params, state.scale.exps, "p:")
+                    all_stats.update(pstats)
+                    if policy.quantize_momentum and opt_cfg.kind == "sgd":
+                        new_mom, mstats = _map_with_group(
+                            q_store, new_opt["momentum"], state.scale.exps,
+                            "pm:")
+                        new_opt = {"momentum": new_mom}
+                        all_stats.update(mstats)
+            elif policy.enabled:
+                # float emulation of the storage format (fp16/bf16/fp8 rows)
+                from repro.core.quant import float_round
+                fmt = policy.update_format()
+                new_params = jax.tree.map(lambda x: float_round(x, fmt),
+                                          new_params)
 
         # ---- scale controller ----------------------------------------------
-        new_scale = state.scale
-        acc_window = None
-        if dyn:
-            new_scale = accumulate(new_scale, all_stats)
-            acc_window = new_scale.acc    # pre-reset §5 window accumulators
-            apply = (state.step + 1) % policy.update_interval == 0
-            new_scale = controller_step(
-                new_scale, max_overflow_rate=policy.max_overflow_rate,
-                apply=apply)
+        with jax.named_scope("controller"):
+            new_scale = state.scale
+            acc_window = None
+            if dyn:
+                new_scale = accumulate(new_scale, all_stats)
+                acc_window = new_scale.acc    # pre-reset §5 window accums
+                apply = (state.step + 1) % policy.update_interval == 0
+                new_scale = controller_step(
+                    new_scale, max_overflow_rate=policy.max_overflow_rate,
+                    apply=apply)
 
         metrics = {"loss": loss, "grad_norm": gnorm,
                    "step": state.step.astype(jnp.float32)}
@@ -315,44 +330,45 @@ def make_train_step(
                                scale=new_scale, step=state.step + 1)
 
         if supervise:
-            from repro.core.tape import tensor_class
-            bad_loss = ~jnp.isfinite(loss)
-            bad_grad = ~jnp.isfinite(gnorm)
-            cls_ovf: Dict[str, Array] = {}
-            cls_tot: Dict[str, Array] = {}
-            for gname, st in all_stats.items():
-                c = tensor_class(gname)
-                cls_ovf[c] = cls_ovf.get(c, 0.0) + jnp.sum(st[..., 0])
-                cls_tot[c] = cls_tot.get(c, 0.0) + jnp.sum(st[..., 2])
-            cls_rates = {c: cls_ovf[c] / jnp.maximum(cls_tot[c], 1.0)
-                         for c in sorted(cls_ovf)}
-            runaway = jnp.bool_(False)
-            if runaway_ovf is not None and cls_rates:
-                runaway = (jnp.stack(list(cls_rates.values())).max()
-                           > runaway_ovf)
-            flags = (bad_loss.astype(jnp.int32) * FLAG_LOSS_NONFINITE
-                     + bad_grad.astype(jnp.int32) * FLAG_GRAD_NONFINITE
-                     + runaway.astype(jnp.int32) * FLAG_RUNAWAY_OVF)
-            metrics["flags"] = flags
-            metrics["cls_rates"] = cls_rates
+            with jax.named_scope("sentinels"):
+                from repro.core.tape import tensor_class
+                bad_loss = ~jnp.isfinite(loss)
+                bad_grad = ~jnp.isfinite(gnorm)
+                cls_ovf: Dict[str, Array] = {}
+                cls_tot: Dict[str, Array] = {}
+                for gname, st in all_stats.items():
+                    c = tensor_class(gname)
+                    cls_ovf[c] = cls_ovf.get(c, 0.0) + jnp.sum(st[..., 0])
+                    cls_tot[c] = cls_tot.get(c, 0.0) + jnp.sum(st[..., 2])
+                cls_rates = {c: cls_ovf[c] / jnp.maximum(cls_tot[c], 1.0)
+                             for c in sorted(cls_ovf)}
+                runaway = jnp.bool_(False)
+                if runaway_ovf is not None and cls_rates:
+                    runaway = (jnp.stack(list(cls_rates.values())).max()
+                               > runaway_ovf)
+                flags = (bad_loss.astype(jnp.int32) * FLAG_LOSS_NONFINITE
+                         + bad_grad.astype(jnp.int32) * FLAG_GRAD_NONFINITE
+                         + runaway.astype(jnp.int32) * FLAG_RUNAWAY_OVF)
+                metrics["flags"] = flags
+                metrics["cls_rates"] = cls_rates
 
-            # Discard a tripped step's update on device: SKIPPED costs no
-            # extra host round-trip before the next step can launch.
-            nan_bad = bad_loss | bad_grad
-            any_bad = nan_bad | runaway
+                # Discard a tripped step's update on device: SKIPPED costs no
+                # extra host round-trip before the next step can launch.
+                nan_bad = bad_loss | bad_grad
+                any_bad = nan_bad | runaway
 
-            def sel(pred, old, new):
-                return jax.tree.map(lambda a, b: jnp.where(pred, a, b),
-                                    old, new)
+                def sel(pred, old, new):
+                    return jax.tree.map(lambda a, b: jnp.where(pred, a, b),
+                                        old, new)
 
-            new_state = TrainState(
-                params=sel(any_bad, state.params, new_state.params),
-                opt=sel(any_bad, state.opt, new_state.opt),
-                # runaway-only: keep the new scale so the §5 controller
-                # can move the exponent out of the overflow regime
-                scale=sel(nan_bad, state.scale, new_state.scale),
-                step=jnp.where(any_bad, state.step, new_state.step))
-            new_ef = sel(any_bad, ef, new_ef)
+                new_state = TrainState(
+                    params=sel(any_bad, state.params, new_state.params),
+                    opt=sel(any_bad, state.opt, new_state.opt),
+                    # runaway-only: keep the new scale so the §5 controller
+                    # can move the exponent out of the overflow regime
+                    scale=sel(nan_bad, state.scale, new_state.scale),
+                    step=jnp.where(any_bad, state.step, new_state.step))
+                new_ef = sel(any_bad, ef, new_ef)
 
         return new_state, metrics, new_ef
 

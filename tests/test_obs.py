@@ -23,6 +23,7 @@ from repro.obs import (
     count_moves,
     read_jsonl,
     serve_records,
+    span,
     start_http_server,
     train_records,
     validate_trace,
@@ -77,7 +78,7 @@ def test_span_nesting_and_export():
 
 def test_export_roundtrip(tmp_path):
     tr = Tracer()
-    with tr.span("decode_step", n_active=2):
+    with span("decode_step", tr, n_active=2):
         tr.instant("submit", tid="requests")
     path = tr.export(str(tmp_path / "t.json"))
     obj = json.load(open(path))

@@ -270,13 +270,12 @@ def test_chaos_sweep_every_step_resolves(tmp_path, seed):
     """A seeded fault mix (NaN bursts, spikes, tears, bit flips) always
     terminates with every attempt resolved to an outcome — no raw
     tracebacks, no unresolved steps."""
-    from repro.obs import MetricsRegistry, Tracer
+    from repro.obs import Tracer
     pol = dataclasses.replace(DFXP, storage="packed")
     faults = chaos_plan(seed, n_steps=14, burst=4)
     assert faults                     # both seeds draw a non-empty plan
     mgr = CheckpointManager(str(tmp_path), retries=0, backoff_s=0.0)
-    h = FaultHarness(faults, seed=seed, tracer=Tracer(),
-                     metrics=MetricsRegistry())
+    h = FaultHarness(faults, seed=seed, tracer=Tracer())
     sup = _sup(pol, manager=mgr, ckpt_every=2, skip_budget=2, faults=h,
                bundle_dir=str(tmp_path / "bundle"))
     summary = sup.run(14)
@@ -290,14 +289,3 @@ def test_chaos_sweep_every_step_resolves(tmp_path, seed):
     # fault log serializes (the CI artifact)
     json.dumps(h.summary())
 
-
-def test_supervisor_outcome_counters_in_metrics():
-    from repro.obs import MetricsRegistry
-    reg = MetricsRegistry()
-    h = FaultHarness([GradNaN(step=1)], metrics=reg)
-    sup = _sup(faults=h, skip_budget=10, metrics=reg)
-    sup.run(4)
-    snap = reg.snapshot()
-    assert snap["train_steps_ok"]["value"] == 3
-    assert snap["train_steps_skipped"]["value"] == 1
-    assert snap["train_faults_injected"]["value"] == 1
