@@ -26,6 +26,20 @@ base PRNG key, the dist error-feedback residual buffers, and the data
 cursor.  ``train N steps solo == train K, crash, restore, train N-K``
 holds bit-for-bit, for deterministic and stochastic rounding (the
 per-step key derives from ``fold_in(base, cursor)``, both checkpointed).
+
+The loop keeps one attempt in flight.  Every outcome short of a rollback
+adopts the step's output state (a SKIPPED step's in-jit select has
+already frozen it) and advances the cursor by one, so attempt N+1's
+inputs exist as soon as N is dispatched.  ``step_once`` therefore
+dispatches N+1 before it blocks on N's flags and loss, and the host's
+batch, launch and bookkeeping overlap the device's work on N.  The next
+call uses that attempt only while its inputs are still the supervisor's
+(the same ``state``, ``ef``, ``rng``, ``batch_fn`` and step program
+objects, and the same cursor); a rollback, ``resume()`` or a replaced
+step program discards it and the attempt is dispatched again, so the
+records, state and checkpoints are the serial loop's bit for bit.  With
+a :class:`~repro.train.faults.FaultHarness` attached, which reads and
+mutates the supervisor between attempts, the loop stays serial.
 """
 from __future__ import annotations
 
@@ -66,7 +80,7 @@ class StepRecord:
     flags: int                  # sentinel bitmask (step.FLAG_*)
     loss: float
     info: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    seconds: float = 0.0        # host time of launch and fetches
+    seconds: float = 0.0        # host time of the call that resolved it
 
     def to_json(self) -> dict:
         return {"cursor": self.cursor, "outcome": self.outcome.value,
@@ -83,6 +97,17 @@ def flag_names(flags: int) -> List[str]:
     if flags & FLAG_RUNAWAY_OVF:
         out.append("runaway_ovf")
     return out
+
+
+@dataclasses.dataclass
+class _Attempt:
+    """A dispatched step attempt: what it was launched with, and its
+    (possibly still computing) outputs."""
+    cursor: int
+    inputs: tuple               # state, ef, rng, batch_fn, step program
+    new_state: TrainState
+    metrics: Dict[str, Array]
+    new_ef: Any
 
 
 class TrainSupervisor:
@@ -107,7 +132,7 @@ class TrainSupervisor:
     * ``faults`` — a :class:`repro.train.faults.FaultHarness`.
     * ``tracer``/``metrics``/``numerics_log`` — repro.obs hooks; all
       optional and zero-cost when absent.  ``metrics`` holds the
-      ``train_host_fetches`` counter.
+      ``train_host_fetches`` and ``train_ahead_discarded`` counters.
     * ``bundle_dir`` — where the HALTED diagnostic bundle lands.
     """
 
@@ -163,6 +188,10 @@ class TrainSupervisor:
         self._consec_skips = 0
         self._rollback_failures = 0
         self._last_commit: Optional[int] = None
+        self._benign = benign_injection()
+        self._ahead: Optional[_Attempt] = None   # the attempt in flight
+        self._n_ahead = 0                       # attempts launched ahead
+        self._n_discarded = 0                   # ... and discarded
 
         if metrics is None:
             from repro.obs import MetricsRegistry
@@ -170,6 +199,9 @@ class TrainSupervisor:
         self.metrics = metrics
         self._c_fetch = metrics.counter(
             "train_host_fetches", "device-to-host fetches of the supervisor")
+        self._c_discard = metrics.counter(
+            "train_ahead_discarded",
+            "attempts launched ahead whose inputs changed before use")
 
     # -- checkpoint tree ---------------------------------------------------
     def ckpt_tree(self) -> dict:
@@ -234,14 +266,27 @@ class TrainSupervisor:
     def step_once(self) -> StepRecord:
         """One supervised step attempt; resolves to a StepRecord.
 
-        Its phases are :class:`repro.obs.span`s, each with the data
-        cursor as its argument: ``train.step`` holds ``train.batch``
-        (host rows and their transfer), ``train.launch`` (the step
-        program dispatched), ``train.wait`` (the flags and loss fetches,
-        the host blocked on the device) and ``train.record`` (outcome
-        bookkeeping, numerics, checkpoint).  ``train.step`` ends noting
-        ``fetches``, this attempt's device-to-host fetches.
+        Without a fault harness the call first dispatches the next
+        attempt (cursor + 1, on this attempt's output state), then blocks
+        on this attempt's flags and loss; the next call resolves that
+        attempt if its inputs are still the supervisor's, and discards
+        and re-dispatches it otherwise (``train_ahead_discarded``).  With
+        a harness attached, nothing is launched ahead.
+
+        Its phases are :class:`repro.obs.span`s, each with the cursor of
+        the attempt it serves: ``train.step`` (this attempt's cursor)
+        holds ``train.batch`` (host rows and their transfer) and
+        ``train.launch`` (the step program dispatched) of each attempt it
+        dispatches, ``train.wait`` (the flags and loss fetches, the host
+        blocked on the device) and ``train.record`` (outcome bookkeeping,
+        numerics, checkpoint).  ``train.step`` ends noting ``fetches``,
+        this attempt's device-to-host fetches, and ``ahead``, 1 when the
+        attempt it resolved had been launched by the previous call.
         """
+        return self._step(ahead=self.faults is None)
+
+    def _step(self, ahead: bool) -> StepRecord:
+        """:meth:`step_once`, dispatching the next attempt if ``ahead``."""
         if self.halted:
             raise RuntimeError("supervisor is HALTED; inspect the bundle "
                                f"at {self.bundle_dir!r}")
@@ -250,29 +295,68 @@ class TrainSupervisor:
         with obs_span("train.step", tr, "train", cursor=cursor) as step:
             if self.faults is not None:
                 self.faults.on_step(self)
-            inj = (self.faults.injection(self) if self.faults is not None
-                   else benign_injection())
-            with obs_span("train.batch", tr, "train", cursor=cursor):
-                batch = self.batch_fn(cursor)
-            with obs_span("train.launch", tr, "train",
-                          cursor=cursor) as launch:
-                rng = jax.random.fold_in(self.rng, cursor)
-                new_state, metrics, new_ef = self._step_fn(
-                    self.state, batch, rng, self.ef, inj)
+            att = self._take_ahead()
+            if self.faults is None:
+                step.note(ahead=int(att is not None))
+            if att is None:
+                inj = (self.faults.injection(self)
+                       if self.faults is not None else self._benign)
+                att = self._launch(cursor, self.state, self.ef, inj)
+            if ahead:
+                self._ahead = self._launch(cursor + 1, att.new_state,
+                                           att.new_ef, self._benign)
+                self._n_ahead += 1
             with obs_span("train.wait", tr, "train", cursor=cursor) as wait:
-                flags = int(np.asarray(metrics["flags"]))  # one extra fetch
-                loss = float(np.asarray(metrics["loss"]))
+                flags = int(np.asarray(att.metrics["flags"]))  # extra fetch
+                loss = float(np.asarray(att.metrics["loss"]))
                 self._c_fetch.inc(2)
             with obs_span("train.record", tr, "train", cursor=cursor):
-                rec = self._record(cursor, flags, loss, metrics,
-                                   new_state, new_ef)
-            rec.seconds = wait.t1 - launch.t0   # launch and the fetches
+                rec = self._record(cursor, flags, loss, att.metrics,
+                                   att.new_state, att.new_ef)
+            rec.seconds = wait.t1 - step.t0
             step.note(fetches=int(self._c_fetch.value - fetched))
         if rec.outcome is StepOutcome.HALTED:
+            self._discard_ahead()
             # after the spans close: the bundle's trace holds this attempt
             bundle = self.write_bundle()
             self._event("halted", cursor=rec.cursor, bundle=bundle)
         return rec
+
+    def _inputs(self, state, ef) -> tuple:
+        """What an attempt on ``state``/``ef`` is launched with."""
+        return (state, ef, self.rng, self.batch_fn, self._step_fn)
+
+    def _launch(self, cursor: int, state, ef, inj) -> _Attempt:
+        """Build the batch at ``cursor`` and dispatch its attempt."""
+        tr = self.tracer
+        with obs_span("train.batch", tr, "train", cursor=cursor):
+            batch = self.batch_fn(cursor)
+        with obs_span("train.launch", tr, "train", cursor=cursor):
+            rng = jax.random.fold_in(self.rng, cursor)
+            new_state, metrics, new_ef = self._step_fn(state, batch, rng,
+                                                       ef, inj)
+        return _Attempt(cursor, self._inputs(state, ef), new_state, metrics,
+                        new_ef)
+
+    def _take_ahead(self) -> Optional[_Attempt]:
+        """The attempt launched ahead, if the serial loop would launch
+        the same one now; discards it otherwise."""
+        att = self._ahead
+        if att is None:
+            return None
+        now = self._inputs(self.state, self.ef)
+        if (self.faults is None and att.cursor == self.cursor
+                and all(a is b for a, b in zip(att.inputs, now))):
+            self._ahead = None
+            return att
+        self._discard_ahead()
+        return None
+
+    def _discard_ahead(self) -> None:
+        if self._ahead is not None:
+            self._ahead = None
+            self._n_discarded += 1
+            self._c_discard.inc()
 
     def _record(self, cursor: int, flags: int, loss: float, metrics,
                 new_state, new_ef) -> StepRecord:
@@ -349,16 +433,20 @@ class TrainSupervisor:
         """Drive ``num_steps`` attempts (or until HALTED / ``stop()``).
 
         Never raises for faults — every attempt lands in
-        :attr:`outcomes`; returns :meth:`summary`.
+        :attr:`outcomes`; returns :meth:`summary`.  The last attempt
+        launches none ahead, and a stop discards the one in flight, so
+        the final commit and cursor are the serial loop's.
         """
-        for _ in range(num_steps):
+        for i in range(num_steps):
             if self.halted or (stop is not None and stop()):
                 break
-            rec = self.step_once()
+            rec = self._step(ahead=self.faults is None
+                             and i < num_steps - 1)
             if log_every and rec.outcome is StepOutcome.OK and \
                     len(self.losses) % log_every == 0:
                 print(f"step {int(self.state.step)}: loss={rec.loss:.4f}",
                       flush=True)
+        self._discard_ahead()
         if not self.halted:
             self.commit(sync=True)
         return self.summary()
@@ -380,6 +468,8 @@ class TrainSupervisor:
             "halted": self.halted,
             "rollback_failures": self._rollback_failures,
             "last_checkpoint": self._last_commit,
+            "launched_ahead": self._n_ahead,
+            "ahead_discarded": self._n_discarded,
             "faults": (self.faults.summary()["event_counts"]
                        if self.faults is not None else {}),
         }

@@ -14,8 +14,8 @@ from repro.obs import (MetricsRegistry, NumericsLog, Tracer, span,
                        validate_trace)
 from repro.obs import trace as obs_trace
 from repro.optim.opt import OptConfig, sgd_init
-from repro.train import (StepOutcome, TrainSupervisor, init_train_state,
-                         make_train_step)
+from repro.train import (FaultHarness, StepOutcome, TrainSupervisor,
+                         init_train_state, make_train_step)
 from repro.train.step import benign_injection
 
 CFG = MX.MaxoutConfig(hidden=(16, 16), pieces=2)
@@ -104,10 +104,22 @@ def test_span_without_tracer_calls_no_tracer_method(monkeypatch):
 
 # ------------------------------------------------------------ the supervisor
 
-def test_profiler_capture_nests_supervisor_phases(tmp_path):
+# The serial path (a fault harness attached) runs each attempt's four
+# phases in turn; the pipelined path (none attached) dispatches the next
+# attempt before it waits, so its batch and launch carry cursor + 1.
+PATHS = pytest.mark.parametrize("serial", [True, False],
+                                ids=["serial", "pipelined"])
+
+
+def _path_sup(serial, **kw):
+    return _sup(faults=FaultHarness([]) if serial else None, **kw)
+
+
+@PATHS
+def test_profiler_capture_nests_supervisor_phases(tmp_path, serial):
     """A CPU ``jax.profiler`` capture of supervised steps holds each
     ``repro:train.step`` with its four phases nested, in order."""
-    sup = _sup()
+    sup = _path_sup(serial)
     sup.step_once()                       # compile outside the capture
     jax.profiler.start_trace(str(tmp_path))
     try:
@@ -125,25 +137,42 @@ def test_profiler_capture_nests_supervisor_phases(tmp_path):
     steps = [e for e in evs if e[0] == "train.step"]
     assert [e[3]["cursor"] for e in steps] == [1, 2, 3]
     assert all(e[3]["fetches"] == 2 for e in steps)
+    assert all(("ahead" not in e[3]) if serial else e[3]["ahead"] == 1
+               for e in steps)
     for name, s0, s1, stats in steps:
         inner = [e for e in evs if e[0] != "train.step"
                  and s0 <= e[1] and e[2] <= s1]
         assert [e[0] for e in inner] == PHASES
-        assert all(e[3]["cursor"] == stats["cursor"] for e in inner)
+        ahead = 0 if serial else 1
+        assert [e[3]["cursor"] - stats["cursor"] for e in inner] == \
+            [ahead, ahead, 0, 0]
         for a, b in zip(inner, inner[1:]):
             assert a[2] <= b[1]           # one after the other
 
 
-def test_supervisor_phases_on_tracer_and_step_seconds():
+@PATHS
+def test_supervisor_phases_on_tracer_and_step_seconds(serial):
     tr = Tracer()
-    sup = _sup(tracer=tr)
+    sup = _path_sup(serial, tracer=tr)
     recs = [sup.step_once() for _ in range(2)]
     names = tr.span_names()
     assert "train_step" not in names
-    assert names == (PHASES + ["train.step"]) * 2
     steps = tr.find("train.step", "X")
-    assert [s["args"] for s in steps] == [{"cursor": 0, "fetches": 2},
-                                          {"cursor": 1, "fetches": 2}]
+    if serial:
+        assert names == (PHASES + ["train.step"]) * 2
+        assert [s["args"] for s in steps] == [{"cursor": 0, "fetches": 2},
+                                              {"cursor": 1, "fetches": 2}]
+    else:
+        # the first call launches cursor 0 and then cursor 1 ahead
+        assert names == (PHASES[:2] + PHASES + ["train.step"]
+                         + PHASES + ["train.step"])
+        assert [s["args"] for s in steps] == [
+            {"cursor": 0, "fetches": 2, "ahead": 0},
+            {"cursor": 1, "fetches": 2, "ahead": 1}]
+        assert [s["args"]["cursor"] for s in tr.find("train.batch", "X")] \
+            == [0, 1, 2]
+        assert [s["args"]["cursor"] for s in tr.find("train.wait", "X")] \
+            == [0, 1]
     for rec, step in zip(recs, steps):
         assert 0 < rec.seconds <= step["dur"] / 1e6
     validate_trace(tr.to_chrome())

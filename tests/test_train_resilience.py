@@ -17,6 +17,7 @@ from repro.train import (FaultHarness, GradNaN, LossSpike, ParamBitFlip,
                          StepOutcome, TrainSupervisor, chaos_plan,
                          init_train_state)
 from repro.train.faults import CkptTear
+from repro.train.step import FLAG_LOSS_NONFINITE, benign_injection
 
 CFG = MX.MaxoutConfig(hidden=(48, 48), pieces=3)
 GS = MX.group_shapes(CFG)
@@ -152,25 +153,27 @@ def test_double_rollback_failure_halts_with_bundle(tmp_path):
 
 
 def _resume_pair(policy, *, tmp_path, n=10, k=6, compress_bits=None,
-                 seed=0):
+                 seed=0, lookahead=True):
     """Train ``n`` straight vs train ``k``, 'crash', restore, train n-k.
 
-    Returns (solo_losses, resumed_losses, solo_state, resumed_state).
+    ``lookahead=False`` attaches an empty fault harness, which keeps the
+    supervisor's loop serial.  Returns (solo, resumed) supervisors.
     """
-    solo = _sup(policy, compress_bits=compress_bits,
-                rng=jax.random.PRNGKey(seed))
+    def sup(**kw):
+        if not lookahead:
+            kw["faults"] = FaultHarness([])
+        return _sup(policy, compress_bits=compress_bits, **kw)
+
+    solo = sup(rng=jax.random.PRNGKey(seed))
     solo.run(n)
 
     d = str(tmp_path / "ck")
-    first = _sup(policy, compress_bits=compress_bits,
-                 rng=jax.random.PRNGKey(seed),
-                 manager=CheckpointManager(d))
+    first = sup(rng=jax.random.PRNGKey(seed), manager=CheckpointManager(d))
     first.run(k)                     # run() commits synchronously at end
     del first                        # the "crash"
 
-    second = _sup(policy, compress_bits=compress_bits,
-                  rng=jax.random.PRNGKey(4242),   # wrong seed on purpose:
-                  manager=CheckpointManager(d))   # ckpt must carry the key
+    second = sup(rng=jax.random.PRNGKey(4242),    # wrong seed on purpose:
+                 manager=CheckpointManager(d))    # ckpt must carry the key
     assert second.resume() == k
     second.run(n - k)
     return solo, second
@@ -183,11 +186,15 @@ def _assert_bit_identical(solo, resumed, k):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_bit_exact_resume_deterministic(tmp_path):
+@pytest.mark.parametrize("lookahead", [True, False],
+                         ids=["lookahead", "serial"])
+def test_bit_exact_resume_deterministic(tmp_path, lookahead):
     """K=6 lands mid-§5-window (interval 4): the pre-reset acc counters
     must be checkpointed for the cursor-8 controller move to agree."""
-    solo, resumed = _resume_pair(DFXP, tmp_path=tmp_path, n=10, k=6)
+    solo, resumed = _resume_pair(DFXP, tmp_path=tmp_path, n=10, k=6,
+                                 lookahead=lookahead)
     _assert_bit_identical(solo, resumed, 6)
+    assert resumed.summary()["launched_ahead"] == (3 if lookahead else 0)
 
 
 def test_bit_exact_resume_stochastic_fused(tmp_path):
@@ -210,6 +217,205 @@ def test_bit_exact_resume_error_feedback_packed(tmp_path):
     assert any(float(jnp.max(jnp.abs(leaf))) > 0
                for leaf in jax.tree.leaves(solo.ef))
     _assert_bit_identical(solo, resumed, 5)
+
+
+# ------------------------------------------------------------ the lookahead
+#
+# Without a fault harness the supervisor dispatches attempt N+1 before it
+# blocks on N.  Each case below must equal a serial loop over the same
+# jitted step bit for bit: a plain dispatch-block-adopt loop where no
+# rollback happens, else the supervisor kept serial by an empty harness.
+
+
+def _cursor_batch_fn(cursor):
+    return {**_batch_fn(cursor), "cursor": cursor}
+
+
+def _skip_at(step_fn, cursors):
+    """``step_fn`` (fed :func:`_cursor_batch_fn`'s batches) that reports
+    the loss sentinel at ``cursors`` and, like the in-jit select, returns
+    the state and residuals it was given there."""
+    def wrapped(state, batch, rng, ef, inj):
+        batch = dict(batch)
+        cursor = batch.pop("cursor")
+        new_state, metrics, new_ef = step_fn(state, batch, rng, ef, inj)
+        if cursor in cursors:
+            flags = jnp.int32(FLAG_LOSS_NONFINITE)
+            return state, {**metrics, "flags": flags}, ef
+        return new_state, metrics, new_ef
+    return wrapped
+
+
+def _plain_loop(step_fn, state, ef, rng, batch_fn, cursors):
+    """Dispatch, block on flags and loss, adopt: the serial loop."""
+    recs, inj = [], benign_injection()
+    for c in cursors:
+        state, m, ef = step_fn(state, batch_fn(c), jax.random.fold_in(rng, c),
+                               ef, inj)
+        flags = int(np.asarray(m["flags"]))
+        recs.append((c, "ok" if flags == 0 else "skipped", flags,
+                     float(np.asarray(m["loss"]))))
+    return recs, state, ef
+
+
+def _recs(sup):
+    return [(r.cursor, r.outcome.value, r.flags, r.loss) for r in sup.outcomes]
+
+
+def _assert_trees_equal(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _last_ckpt(sup):
+    sup.manager.wait()
+    return sup.manager.restore_latest(sup.ckpt_template())
+
+
+def test_lookahead_clean_run_equals_serial_loop(tmp_path):
+    """A clean run with error feedback: every attempt after the first was
+    launched ahead and none discarded; records, state, residuals and the
+    final checkpoint are the plain loop's."""
+    sup = _sup(compress_bits=8, manager=CheckpointManager(str(tmp_path)),
+               ckpt_every=2)
+    state0, ef0 = sup.state, sup.ef
+    summary = sup.run(6)
+    recs, state, ef = _plain_loop(sup._step_fn, state0, ef0, sup.rng,
+                                  _batch_fn, range(6))
+    assert _recs(sup) == recs
+    _assert_trees_equal(sup.state, state)
+    _assert_trees_equal(sup.ef, ef)
+    tree, step = _last_ckpt(sup)
+    assert step == 6
+    _assert_trees_equal(tree, {"train": state, "ef": ef, "rng": sup.rng,
+                               "cursor": jnp.int32(6)})
+    assert summary["launched_ahead"] == 5           # none past the last
+    assert summary["ahead_discarded"] == 0
+    assert sup.metrics.snapshot()["train_ahead_discarded"]["value"] == 0
+
+
+def test_lookahead_skipped_attempts_equal_serial_loop():
+    sup = _sup(batch_fn=_cursor_batch_fn, skip_budget=10)
+    sup._step_fn = _skip_at(sup._step_fn, {2, 3})
+    state0, ef0 = sup.state, sup.ef
+    summary = sup.run(6)
+    recs, state, ef = _plain_loop(sup._step_fn, state0, ef0, sup.rng,
+                                  _cursor_batch_fn, range(6))
+    assert [r[1] for r in recs] == ["ok", "ok", "skipped", "skipped", "ok",
+                                    "ok"]
+    assert _recs(sup) == recs
+    _assert_trees_equal(sup.state, state)
+    assert summary["steps_committed"] == 4
+    assert summary["ahead_discarded"] == 0
+
+
+def _lookahead_and_serial(tmp_path, skip, **kw):
+    """The same supervisor twice over one jitted step, skipping at
+    ``skip``: with the lookahead, and kept serial by an empty harness."""
+    pair = []
+    for name, faults in (("ahead", None), ("serial", FaultHarness([]))):
+        mgr = CheckpointManager(str(tmp_path / name), retries=0,
+                                backoff_s=0.0)
+        pair.append(_sup(batch_fn=_cursor_batch_fn, manager=mgr,
+                         faults=faults, bundle_dir=str(tmp_path / name / "b"),
+                         **kw))
+    ahead, serial = pair
+    ahead._step_fn = serial._step_fn = _skip_at(ahead._step_fn, skip)
+    return ahead, serial
+
+
+def test_lookahead_rollback_discards_and_reruns(tmp_path):
+    """Three skips over a budget of two roll back to the cursor-2
+    checkpoint: the attempt launched ahead on the frozen state is
+    discarded and cursor 6 runs again on the restored state."""
+    ahead, serial = _lookahead_and_serial(tmp_path, {3, 4, 5},
+                                          ckpt_every=2, skip_budget=2)
+    a, b = ahead.run(9), serial.run(9)
+    assert [r.outcome for r in ahead.outcomes].count(
+        StepOutcome.ROLLED_BACK) == 1
+    assert ahead.outcomes[5].info["restored"] == 2
+    assert _recs(ahead) == _recs(serial)
+    _assert_trees_equal(ahead.ckpt_tree(), serial.ckpt_tree())
+    (ta, sa), (tb, sb) = _last_ckpt(ahead), _last_ckpt(serial)
+    assert sa == sb == 9
+    _assert_trees_equal(ta, tb)
+    assert a["ahead_discarded"] == 1 and b["ahead_discarded"] == 0
+    assert ahead.metrics.snapshot()["train_ahead_discarded"]["value"] == 1
+    assert {k: v for k, v in a.items() if "ahead" not in k and k != "faults"} \
+        == {k: v for k, v in b.items() if "ahead" not in k and k != "faults"}
+
+
+def test_lookahead_halted_leaves_nothing_in_flight(tmp_path):
+    """No checkpoint to restore: a failed rollback keeps the inputs (the
+    attempt launched ahead is used), the second halts and drops it."""
+    ahead, serial = _lookahead_and_serial(tmp_path, set(range(50)),
+                                          skip_budget=1)
+    ahead.manager = serial.manager = None
+    a, b = ahead.run(50), serial.run(50)
+    assert a["halted"] and b["halted"]
+    assert _recs(ahead) == _recs(serial)
+    assert ahead.outcomes[-1].outcome is StepOutcome.HALTED
+    _assert_trees_equal(ahead.ckpt_tree(), serial.ckpt_tree())
+    assert ahead._ahead is None
+    assert a["ahead_discarded"] == 1
+    assert a["launched_ahead"] == a["attempts"]
+    with pytest.raises(RuntimeError):
+        ahead.step_once()
+
+
+def test_lookahead_stop_commits_the_serial_cursor(tmp_path):
+    """``run(stop=...)`` discards the attempt in flight: the final commit
+    holds the cursor and state of the attempts resolved."""
+    sup = _sup(manager=CheckpointManager(str(tmp_path)))
+    state0, ef0 = sup.state, sup.ef
+    summary = sup.run(10, stop=lambda: len(sup.outcomes) >= 4)
+    recs, state, ef = _plain_loop(sup._step_fn, state0, ef0, sup.rng,
+                                  _batch_fn, range(4))
+    assert _recs(sup) == recs
+    assert summary["cursor"] == 4 and sup._ahead is None
+    tree, step = _last_ckpt(sup)
+    assert step == 4
+    _assert_trees_equal(tree, {"train": state, "ef": ef, "rng": sup.rng,
+                               "cursor": jnp.int32(4)})
+    assert summary["launched_ahead"] == 4
+    assert summary["ahead_discarded"] == 1
+
+
+def test_lookahead_replaced_step_fn_runs_from_next_attempt():
+    """A step program swapped in between calls (as a harness plants a
+    fault) runs from the next attempt on: the one launched ahead with
+    the old program is discarded."""
+    sup = _sup(batch_fn=_cursor_batch_fn, skip_budget=10)
+    inner = sup._step_fn
+    state0, ef0 = sup.state, sup.ef
+    sup.step_once()                                  # cursor 1 in flight
+    sup._step_fn = _skip_at(inner, {1, 2})
+    sup.run(3)
+    head, s1, e1 = _plain_loop(_skip_at(inner, set()), state0, ef0, sup.rng,
+                               _cursor_batch_fn, range(1))
+    tail, state, _ = _plain_loop(sup._step_fn, s1, e1, sup.rng,
+                                 _cursor_batch_fn, range(1, 4))
+    assert _recs(sup) == head + tail
+    assert [r.outcome.value for r in sup.outcomes] == ["ok", "skipped",
+                                                       "skipped", "ok"]
+    _assert_trees_equal(sup.state, state)
+    assert sup.summary()["ahead_discarded"] == 1
+
+
+def test_lookahead_discarded_after_resume(tmp_path):
+    """``resume()`` adopts the checkpoint's state and cursor: the attempt
+    in flight is discarded and the restored cursor runs again, reading
+    the loss it read before."""
+    sup = _sup(manager=CheckpointManager(str(tmp_path)))
+    sup.run(3)                                        # commits at 3
+    first = sup.step_once()                           # cursor 3
+    sup.step_once()                                   # cursor 5 in flight
+    assert sup.resume() == 3
+    again = sup.step_once()
+    assert (again.cursor, again.loss) == (first.cursor, first.loss)
+    assert sup.summary()["ahead_discarded"] == 1
 
 
 # -------------------------------------------------------------- host faults
