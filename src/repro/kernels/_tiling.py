@@ -9,7 +9,7 @@ contribute exactly 0.0 to f32 dot-product accumulations.
 Block heuristics live here so the two ``ops.py`` wrappers and the
 dispatch layer agree on one notion of "tile-friendly"; the measured
 autotune cache in :mod:`repro.kernels.dispatch` overrides these numbers
-per shape bucket on compiled backends.
+per shape on compiled backends.
 """
 from __future__ import annotations
 

@@ -12,11 +12,13 @@ four concerns the kernels themselves stay agnostic of:
   * **shape collapsing** — batched/ND left operands ``[..., K]`` are
     flattened to ``[M, K]`` around the kernel call (reshape is exact and
     linear, so autodiff through it is free);
-  * **block selection** — shape-bucketed, with a small measured autotune
-    cache: on compiled backends the first matmul in a bucket times a
-    handful of candidate tilings on dummy operands and the winner is
-    cached; in interpret mode (no real perf to measure) the shared
-    heuristic is cached instead;
+  * **block selection** — by the shape called, with a measured autotune
+    cache: on compiled backends the first matmul of a 128-rounded shape
+    times tiles generated from that shape (large enough to keep the MXU
+    fed, sized to divide the dims where they can), with the operand
+    widths the call uses, and caches the winner; timings within 3% tie
+    and a fixed order settles them, so checkouts on one chip agree.  In
+    interpret mode the exact full-shape blocks stand (bit-identity);
   * **backend detection** — compiled Pallas on TPU, interpret elsewhere,
     resolved once per process (``_tiling.default_interpret``).
 
@@ -28,9 +30,9 @@ Measured entries **persist across processes**: every successful timing
 is serialized to ``<checkout>/.cache/autotune.json`` (override the path
 with the ``REPRO_AUTOTUNE_CACHE`` env var) and loaded back on import, so
 a compiled-TPU autotune run survives restarts instead of re-timing every
-bucket per process.  Heuristic fallbacks are never persisted — only
+shape per process.  Heuristic fallbacks are never persisted — only
 numbers an actual backend produced.  A candidate the compiler refuses is
-counted (the ``refused`` column of the profile table); a bucket whose
+counted (the ``refused`` column of the profile table); a shape whose
 every candidate is refused raises with the compiler's message rather
 than falling back to a tiling nobody compiled.
 
@@ -42,8 +44,11 @@ from __future__ import annotations
 
 import concurrent.futures
 import functools
+import itertools
 import json
+import math
 import os
+import statistics
 import time
 from typing import Dict, Optional, Set, Tuple
 
@@ -51,46 +56,56 @@ import jax
 import jax.numpy as jnp
 
 from repro.compile_cache import CACHE_DIR
-from repro.kernels._tiling import (default_interpret, mm_blocks,
-                                   resolve_interpret, round_up)
+from repro.kernels._tiling import (default_interpret, resolve_interpret,
+                                   round_up)
 from repro.kernels.qmatmul.ops import qmm
+from repro.kernels.qmatmul.qmatmul_kernel import VMEM_LIMIT_BYTES
 
 Array = jax.Array
 
 
 # ---------------------------------------------------------------------------
-# shape-bucketed block selection with a measured autotune cache
+# block selection with a measured autotune cache
 # ---------------------------------------------------------------------------
 
-# Candidate (block_r, block_c, block_d) tilings tried by the autotuner,
-# filtered per shape to fit the operands and a VMEM budget.
-_CANDIDATES = [
-    (128, 128, 128), (128, 128, 256), (128, 128, 512),
-    (128, 256, 128), (256, 128, 128), (256, 256, 128),
-    (128, 256, 256), (512, 128, 128), (128, 512, 128),
-]
+# qmatmul tiles are generated from the shape called, per axis
+# (block_r, block_c, block_d): the two largest 128-multiples up to the
+# axis' largest tile that divide the 128-rounded dim, and the one with
+# the fewest tiles that pads it by at most _QMM_PAD.
+_QMM_TILE_MAX = (512, 1024, 1024)
+_QMM_PAD = 0.02
+# The tile estimate's limit: half the kernel's scoped VMEM, the other
+# half for Mosaic's own temporaries.
+_QMM_VMEM_BUDGET = VMEM_LIMIT_BYTES // 2
+# Candidates timed within this share of the fastest are tied; ties go to
+# the fewest grid steps, then the least padding, then the smaller tile.
+_QMM_TIE = 0.03
+# A qmatmul timing runs the kernel this many times in one program, so
+# that it reads the device and not the host's dispatch of each call.
+_QMM_LOOP = 16
+_TIMED_CALLS = 5            # after one warm call; the median counts
 # Candidate split-K sizes (block_w) for the flash-decode attention kernel.
 _ATTN_CANDIDATES = [128, 256, 512, 1024, 2048]
-_VMEM_BUDGET = 8 * 1024 * 1024  # bytes of f32 tiles per grid step
+_VMEM_BUDGET = 8 * 1024 * 1024  # bytes of attention tiles per grid step
 # What a compiler that refuses a tiling raises: Pallas' block-shape checks
 # (ValueError), unsupported lowerings, and Mosaic/XLA compile failures
 # such as a VMEM overflow.
 _REFUSALS = (ValueError, NotImplementedError, jax.errors.JaxRuntimeError)
 
-_AUTOTUNE: Dict[str, object] = {"measure": True, "reps": 3}
+_AUTOTUNE: Dict[str, object] = {"measure": True}
 _BLOCK_CACHE: Dict[tuple, Tuple[int, ...]] = {}
 _MEASURED: Set[tuple] = set()   # keys whose blocks came from a real timing
 
 # -- dispatch profiling (behind --profile; one dict check when off) ----------
 #
-# Per bucket key: block-selection call count, autotune cache hit/miss
+# Per cache key: block-selection call count, autotune cache hit/miss
 # split, kernel compiles + wall µs spent inside the measurement loops,
 # and the blocks chosen.  Selection runs at trace time (jit caches the
 # result), so recording here never touches a per-token path; with
 # profiling off the only cost is the ``_PROFILE["enabled"]`` check.
 _PROFILE: Dict[str, bool] = {"enabled": False}
 _PROF: Dict[tuple, dict] = {}
-_COUNTS = {"compiles": 0, "refused": 0}   # bumped by _time_candidates
+_COUNTS = {"compiles": 0, "refused": 0}   # bumped by _timings
 
 
 def profile_enable(on: bool = True) -> None:
@@ -105,7 +120,7 @@ def reset_profile() -> None:
 
 
 def profile_stats() -> Dict[tuple, dict]:
-    """Copy of the per-bucket profile: ``{key: {calls, hits, misses,
+    """Copy of the per-key profile: ``{key: {calls, hits, misses,
     compiles, refused, measure_us, blocks}}`` (empty unless profiling
     ran)."""
     return {k: dict(v) for k, v in _PROF.items()}
@@ -133,15 +148,19 @@ def _prof(key: tuple, *, hit: bool, blocks=None, measure_us: float = 0.0,
 
 
 def profile_table() -> str:
-    """The dispatch profile as an aligned text table (one row per bucket)."""
-    rows = [("bucket", "calls", "hit", "miss", "compiles", "refused",
-             "measure_ms", "blocks")]
+    """The dispatch profile as an aligned text table (one row per key;
+    ``steps`` is a qmatmul call's grid steps)."""
+    rows = [("key", "calls", "hit", "miss", "compiles", "refused",
+             "measure_ms", "blocks", "steps")]
     for key in sorted(_PROF, key=str):
         d = _PROF[key]
+        qmm_key = key[0] in ("nn", "nt", "tn") and d["blocks"]
         rows.append(("|".join(map(str, key)), str(d["calls"]),
                      str(d["hits"]), str(d["misses"]), str(d["compiles"]),
                      str(d["refused"]), f"{d['measure_us'] / 1e3:.2f}",
-                     "x".join(map(str, d["blocks"] or ()))))
+                     "x".join(map(str, d["blocks"] or ())),
+                     str(_qmm_order(d["blocks"], *key[1:])[0])
+                     if qmm_key else ""))
     widths = [max(len(r[i]) for r in rows) for i in range(len(rows[0]))]
     return "\n".join("  ".join(c.ljust(w) for c, w in zip(r, widths)).rstrip()
                      for r in rows)
@@ -149,7 +168,7 @@ def profile_table() -> str:
 
 def profile_trace_counters(tracer) -> None:
     """Dump the profile onto a :class:`repro.obs.Tracer` as counter events
-    (one multi-series counter per bucket, on the ``dispatch`` track)."""
+    (one multi-series counter per key, on the ``dispatch`` track)."""
     for key in sorted(_PROF, key=str):
         d = _PROF[key]
         tracer.counter("dispatch/" + "|".join(map(str, key)),
@@ -160,7 +179,8 @@ def profile_trace_counters(tracer) -> None:
 
 
 def _bucket(n: int) -> int:
-    """Round up to the next power of two (min 8) — the cache granularity."""
+    """Round up to the next power of two (min 8): the attention window's
+    cache granularity."""
     b = 8
     while b < n:
         b *= 2
@@ -168,8 +188,9 @@ def _bucket(n: int) -> int:
 
 
 def autotune_cache() -> Dict[tuple, Tuple[int, int, int]]:
-    """The live {(kind, R̂, Ĉ, D̂): blocks} cache (mutable; compiled path
-    only — interpret mode always uses exact full-shape blocks)."""
+    """The live {key: blocks} cache, qmatmul keys ``(kind, R̂, Ĉ, D̂)``
+    (:func:`_qmm_key`; mutable; compiled path only — interpret mode
+    always uses exact full-shape blocks)."""
     return _BLOCK_CACHE
 
 
@@ -195,7 +216,7 @@ def save_autotune(path: Optional[str] = None) -> Optional[str]:
     heuristic fallbacks are excluded (they cost nothing to recompute and
     would shadow a future real measurement).  Entries already on disk are
     merged, not clobbered — successive/concurrent processes measure
-    different buckets and each must keep the others' work.  Returns the
+    different keys and each must keep the others' work.  Returns the
     path written, or None when there is nothing measured to persist.
     """
     entries = {"|".join(map(str, key)): list(_BLOCK_CACHE[key])
@@ -224,7 +245,7 @@ def _valid_entry(key: tuple, blocks: tuple) -> bool:
     Guards against hand-edited files, entries written by a different
     version, or measurements from hardware with other limits — a bad
     entry would otherwise be trusted forever (loaded entries count as
-    measured, so nothing ever re-measures the bucket).
+    measured, so nothing ever re-measures the key).
     """
     if key[0] == "attn":
         return (len(key) == 6 and len(blocks) == 1 and blocks[0] > 0
@@ -235,7 +256,7 @@ def _valid_entry(key: tuple, blocks: tuple) -> bool:
     if key[0] in ("nn", "nt", "tn"):
         return (len(key) == 4 and len(blocks) == 3
                 and all(b > 0 for b in blocks)
-                and _fits(blocks, key[1], key[2], key[3]))
+                and _fits(key[0], blocks, *key[1:]))
     return False
 
 
@@ -268,55 +289,123 @@ def load_autotune(path: Optional[str] = None) -> int:
     return n
 
 
-def set_autotune(measure: Optional[bool] = None,
-                 reps: Optional[int] = None) -> None:
+def set_autotune(measure: Optional[bool] = None) -> None:
     if measure is not None:
         _AUTOTUNE["measure"] = measure
-    if reps is not None:
-        _AUTOTUNE["reps"] = reps
 
 
-def _fits(blocks, R, C, D) -> bool:
-    br, bc, bd = blocks
-    # reject blocks larger than the 128-aligned problem (candidates are
-    # all 128-multiples, so this is "no pure-padding tiles")
-    if (br > round_up(R, 128) or bc > round_up(C, 128)
-            or bd > round_up(D, 128)):
-        return False
-    vmem = 4 * (br * bd + bd * bc + 2 * br * bc)
-    return vmem <= _VMEM_BUDGET
+# -- qmatmul tiles -----------------------------------------------------------
+
+def _sublane_only(kind: str) -> Tuple[bool, bool, bool]:
+    """Which of (R, C, D) lie only on sublane axes of the tiles: R in
+    ``nn``/``nt``, D in ``tn`` (see ``mm_blocks``).  Those take multiples
+    of 8; every other axis is a lane axis somewhere and takes 128."""
+    return (False, False, True) if kind == "tn" else (True, False, False)
 
 
-def _time_candidates(key: tuple, cands, call) -> tuple:
-    """Time ``call(c)`` for every candidate; return the fastest.
+def _qmm_key(kind: str, R: int, C: int, D: int) -> tuple:
+    """Cache key: the shape rounded to 128, or to 8 on a sublane-only
+    axis shorter than 128 (decode rows)."""
+    return (kind,) + tuple(
+        round_up(n, 8) if sub and n < 128 else round_up(n, 128)
+        for n, sub in zip((R, C, D), _sublane_only(kind)))
 
-    A candidate the compiler refuses is skipped and counted.  When it
+
+def _qmm_vmem(br: int, bc: int, bd: int) -> int:
+    """VMEM bytes of one grid step: f32 input and output tiles, double
+    buffered; the accumulator and the dot's f32 result; the three bf16
+    pieces the full-precision dot splits each input tile into."""
+    ins, out = br * bd + bd * bc, br * bc
+    return 4 * 2 * ins + 4 * 2 * out + 4 * 2 * out + 2 * 3 * ins
+
+
+def _fits(kind: str, blocks, R: int, C: int, D: int) -> bool:
+    """Aligned (8 or 128 per axis), no larger than the key's rounded
+    dims (no pure-padding tile), and inside the VMEM estimate."""
+    return (all(0 < b <= n and b % (8 if sub else 128) == 0
+                for b, n, sub in zip(blocks, (R, C, D), _sublane_only(kind)))
+            and _qmm_vmem(*blocks) <= _QMM_VMEM_BUDGET)
+
+
+def _axis_tiles(n: int, cap: int) -> list:
+    """Tile sizes for an axis of rounded length ``n``: the two largest
+    128-multiples up to ``cap`` that divide ``n``, and the one with the
+    fewest tiles (then the least padding) that pads ``n`` by at most
+    ``_QMM_PAD``.  A sublane axis under 128 is one tile."""
+    if n < 128:
+        return [n]
+    sizes = range(128, min(cap, n) + 1, 128)
+    divisors = [t for t in sizes if n % t == 0][-2:]
+    fewest = min((t for t in sizes if round_up(n, t) <= n * (1 + _QMM_PAD)),
+                 key=lambda t: (round_up(n, t) // t, round_up(n, t)))
+    return sorted(set(divisors) | {fewest})
+
+
+def _qmm_order(blocks, R: int, C: int, D: int) -> tuple:
+    """The fixed order ties are broken by: fewest grid steps, then the
+    least padding, then the tile itself."""
+    padded = [round_up(n, b) for n, b in zip((R, C, D), blocks)]
+    steps = math.prod(n // b for n, b in zip(padded, blocks))
+    return steps, math.prod(padded), tuple(blocks)
+
+
+def _qmm_candidates(kind: str, R: int, C: int, D: int) -> list:
+    """Candidate tiles for a key's rounded shape, in :func:`_qmm_order`:
+    every combination of :func:`_axis_tiles` that fits, and the smallest
+    tile (128, or the whole of a short sublane axis), which always
+    fits."""
+    axes = [_axis_tiles(n, cap)
+            for n, cap in zip((R, C, D), _QMM_TILE_MAX)]
+    cands = {c for c in itertools.product(*axes) if _fits(kind, c, R, C, D)}
+    cands.add(tuple(min(n, 128) for n in (R, C, D)))
+    return sorted(cands, key=lambda c: _qmm_order(c, R, C, D))
+
+
+# -- measurement --------------------------------------------------------------
+
+def _timings(key: tuple, cands, make) -> Dict[tuple, float]:
+    """Median seconds of ``make(c)()`` for every candidate the compiler
+    takes.
+
+    Each candidate's program is built once and run once to warm it (its
+    compile), then ``_TIMED_CALLS`` times, each timed to completion.  A
+    candidate the compiler refuses is skipped and counted.  When it
     refuses them all, raise with its last message: a heuristic tiling in
     their place would only fail later, inside a serve or train step.
     """
-    reps = max(1, int(_AUTOTUNE["reps"]))
-    best, best_t, err = None, float("inf"), None
+    times, err = {}, None
     for blocks in cands:
+        run = make(blocks)
         try:
-            jax.block_until_ready(call(blocks))  # compile
+            jax.block_until_ready(run())  # compile
         except _REFUSALS as e:
             _COUNTS["refused"] += 1
             err = e
             continue
         _COUNTS["compiles"] += 1
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            out = call(blocks)
-        jax.block_until_ready(out)
-        t = time.perf_counter() - t0
-        if t < best_t:
-            best, best_t = blocks, t
-    if best is None:
+        ts = []
+        for _ in range(_TIMED_CALLS):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run())
+            ts.append(time.perf_counter() - t0)
+        times[blocks] = statistics.median(ts)
+    if not times:
         raise RuntimeError(
             f"kernel dispatch: the compiler refused every candidate tiling "
-            f"of bucket {key} ({len(cands)} tried); last error: "
+            f"of {key} ({len(cands)} tried); last error: "
             f"{type(err).__name__}: {err}") from err
-    return best
+    return times
+
+
+def _fastest(times: Dict[tuple, float], order=None) -> tuple:
+    """The fastest candidate; with ``order``, every candidate within
+    ``_QMM_TIE`` of it is tied and the least by ``order`` wins, so that
+    timing noise does not decide between tiles of equal speed."""
+    best = min(times.values())
+    if order is None:
+        return min(times, key=times.get)
+    return min((c for c, t in times.items() if t <= best * (1 + _QMM_TIE)),
+               key=order)
 
 
 def _on_one_device(fn):
@@ -339,7 +428,7 @@ def _measured(key: tuple, measure, default: tuple) -> tuple:
                   time.perf_counter())
     try:
         measured = _on_one_device(measure) if _AUTOTUNE["measure"] else None
-    finally:   # a bucket whose every candidate was refused still shows
+    finally:   # a key whose every candidate was refused still shows
         _prof(key, hit=False, measure_us=(time.perf_counter() - t0) * 1e6,
               compiles=_COUNTS["compiles"] - n0,
               refused=_COUNTS["refused"] - r0)
@@ -353,29 +442,39 @@ def _measured(key: tuple, measure, default: tuple) -> tuple:
     return blocks
 
 
-def _measure(kind: str, R: int, C: int, D: int, width) -> tuple:
-    """Time candidate tilings on dummy operands; return the fastest."""
-    if kind == "nn":
-        sa, sb = (R, D), (D, C)
-    elif kind == "nt":
-        sa, sb = (R, D), (C, D)
-    else:
-        sa, sb = (D, R), (D, C)
+def _measure(kind: str, R: int, C: int, D: int, width_a, width_b) -> tuple:
+    """Time the candidate tiles at a key's rounded shape, with the call's
+    operand widths, on zero operands; return the winner.
+
+    Each timing runs the kernel ``_QMM_LOOP`` times in one program: the
+    host's dispatch of one call costs about as much as the kernel.  The
+    operands pass an optimization barrier with the loop index, so that
+    every iteration pads and computes anew.
+    """
+    sa = {"nn": (R, D), "nt": (R, D), "tn": (D, R)}[kind]
+    sb = {"nn": (D, C), "nt": (C, D), "tn": (D, C)}[kind]
     a = jnp.zeros(sa, jnp.float32)
     b = jnp.zeros(sb, jnp.float32)
     e = jnp.float32(0.0)
-    cands = [c for c in _CANDIDATES if _fits(c, R, C, D)]
-    if not cands:
-        cands = [mm_blocks(kind, R, C, D)]
-    return _time_candidates(
-        (kind, R, C, D), cands,
-        lambda blocks: qmm(a, b, e, e, kind=kind, width_a=width,
-                           width_b=width, blocks=blocks, interpret=False))
+
+    def make(blocks):
+        @jax.jit
+        def loop(a, b, e):
+            def body(i, acc):
+                ai, bi, _ = jax.lax.optimization_barrier((a, b, i))
+                c = qmm(ai, bi, e, e, kind=kind, width_a=width_a,
+                        width_b=width_b, blocks=blocks, interpret=False)
+                return acc + c.reshape(-1)[0]
+            return jax.lax.fori_loop(0, _QMM_LOOP, body, jnp.float32(0.0))
+        return lambda: loop(a, b, e)
+
+    times = _timings((kind, R, C, D), _qmm_candidates(kind, R, C, D), make)
+    return _fastest(times, lambda c: _qmm_order(c, R, C, D))
 
 
 def blocks_for(kind: str, R: int, C: int, D: int, *, interpret: bool,
-               width=10) -> tuple:
-    """Cached block choice for a shape bucket (measured on compiled TPU).
+               width_a=10, width_b=10) -> tuple:
+    """Cached tile choice for the shape called (measured on compiled TPU).
 
     In interpret mode the blocks are the exact operand dims (one grid
     step, zero padding): the kernel body then executes literally the
@@ -383,17 +482,22 @@ def blocks_for(kind: str, R: int, C: int, D: int, *, interpret: bool,
     fused path *bit*-identical to the jnp composite — f32 accumulation
     order on CPU backends depends on operand shapes, so padding or
     splitting the reduction would drift ULPs on raw (straight-through)
-    operands.  Compiled TPU tilings come from the measured autotune
-    cache instead; there the MXU accumulation contract is the spec.
+    operands.  Compiled TPU tilings are keyed by the 128-rounded shape
+    (:func:`_qmm_key`): the first call of a key times the candidates
+    generated from that shape (:func:`_qmm_candidates`) with the call's
+    operand widths (``None``: raw) and caches the winner; there the MXU
+    accumulation contract is the spec.  With measurement off, the first
+    candidate in tie-break order stands in.
     """
     if interpret:
         _prof(("mm", kind, "interp"), hit=True, blocks=(R, C, D))
         return R, C, D
-    key = (kind, _bucket(R), _bucket(C), _bucket(D))
+    key = _qmm_key(kind, R, C, D)
     blocks = _BLOCK_CACHE.get(key)
     if blocks is None:
-        blocks = _measured(key, lambda: _measure(kind, *key[1:], width),
-                           mm_blocks(kind, R, C, D))
+        blocks = _measured(
+            key, lambda: _measure(*key, width_a, width_b),
+            _qmm_candidates(*key)[0])
     else:
         _prof(key, hit=True, blocks=blocks)
     return blocks
@@ -448,10 +552,11 @@ def _measure_attn(W: int, K: int, G: int, hd: int, width) -> tuple:
     e = jnp.zeros((B,), jnp.float32)
     cands = [(c,) for c in _ATTN_CANDIDATES
              if c <= round_up(W, 128) and _attn_fits(c, K, G, hd, width)]
-    return _time_candidates(
+    return _fastest(_timings(
         ("attn", W, K, G, hd, width), cands,
-        lambda bw: flash_decode(q, kv, kv, pos, qp, e, e, width=width,
-                                scale=1.0, block_w=bw[0], interpret=False))
+        lambda bw: lambda: flash_decode(q, kv, kv, pos, qp, e, e,
+                                        width=width, scale=1.0,
+                                        block_w=bw[0], interpret=False)))
 
 
 def attn_blocks_for(W: int, K: int, G: int, hd: int, *, width=None,
@@ -516,11 +621,11 @@ def _measure_prefill(C: int, K: int, G: int, hd: int, width) -> tuple:
     cands = [(c,) for c in _ATTN_CANDIDATES
              if c <= round_up(W, 128)
              and _prefill_fits(c, C, K, G, hd, width)]
-    return _time_candidates(
+    return _fastest(_timings(
         ("prefill", C, K, G, hd, width), cands,
-        lambda bw: flash_prefill(q, kn, kn, kv, kv, pos, p0, nv, e, e,
-                                 width=width, scale=1.0, block_w=bw[0],
-                                 interpret=False))
+        lambda bw: lambda: flash_prefill(q, kn, kn, kv, kv, pos, p0, nv, e,
+                                         e, width=width, scale=1.0,
+                                         block_w=bw[0], interpret=False)))
 
 
 def prefill_blocks_for(W: int, C: int, K: int, G: int, hd: int, *,
@@ -605,7 +710,7 @@ def _qmm_auto(a, b, e_a, e_b, *, kind, width_a, width_b, cast, out_dtype,
     else:
         (D, R), C = a.shape, b.shape[1]
     blocks = blocks_for(kind, R, C, D, interpret=interpret,
-                        width=width_a or width_b)
+                        width_a=width_a, width_b=width_b)
     return qmm(a, b, e_a, e_b, kind=kind, width_a=width_a, width_b=width_b,
                blocks=blocks, cast=cast, out_dtype=out_dtype,
                interpret=interpret)

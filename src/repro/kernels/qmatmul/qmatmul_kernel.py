@@ -32,6 +32,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.policy import MATMUL_PRECISION
 
+# Scoped VMEM of the kernel.  The dispatch layer's largest tiles, double
+# buffered and with the bf16 pieces of the full-precision dot
+# (MATMUL_PRECISION) on top, outgrow Mosaic's 16 MiB default; the
+# dispatch layer keeps its estimate under half of this (a v5e core has
+# 128 MiB of VMEM).
+VMEM_LIMIT_BYTES = 64 * 2**20
+
 # (lhs contracting dims, rhs contracting dims) per layout.
 _CONTRACT = {"nn": ((1,), (0,)), "nt": ((1,), (1,)), "tn": ((0,), (0,))}
 
@@ -115,5 +122,7 @@ def qmm_2d(a, b, scales, *, kind: str, width_a, width_b, block_r: int,
         out_specs=pl.BlockSpec((block_r, block_c), lambda i, j, r: (i, j)),
         out_shape=jax.ShapeDtypeStruct((R, C), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_r, block_c), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(scales, a, b)

@@ -115,21 +115,101 @@ def test_blocks_interpret_mode_exact_shapes():
 
 
 def test_autotune_cache_bucketing():
+    """Keys are the shape rounded to 128, or to 8 on a sublane-only axis
+    under 128 (R in nn/nt, D in tn): not a power-of-two bucket."""
     dispatch.reset_autotune()
     try:
         dispatch.set_autotune(measure=False)
         b1 = dispatch.blocks_for("nn", 120, 250, 70, interpret=False)
-        assert dispatch.autotune_cache() == {("nn", 128, 256, 128): b1}
-        # same bucket → cache hit; an injected entry wins
-        dispatch.autotune_cache()[("nn", 128, 256, 128)] = (8, 128, 128)
-        assert dispatch.blocks_for("nn", 100, 140, 100, interpret=False) \
+        assert dispatch.autotune_cache() == {("nn", 120, 256, 128): b1}
+        # same rounded shape → cache hit; an injected entry wins
+        dispatch.autotune_cache()[("nn", 120, 256, 128)] = (8, 128, 128)
+        assert dispatch.blocks_for("nn", 117, 140, 100, interpret=False) \
             == (8, 128, 128)
-        # different bucket → new entry
+        # another rounded shape → new entry, where a bucket would have hit
+        dispatch.blocks_for("nn", 100, 250, 70, interpret=False)
+        assert ("nn", 104, 256, 128) in dispatch.autotune_cache()
+        # tn: R is a lane axis (128), D the sublane-only one (8)
         dispatch.blocks_for("tn", 120, 250, 70, interpret=False)
-        assert len(dispatch.autotune_cache()) == 2
+        assert ("tn", 128, 256, 72) in dispatch.autotune_cache()
+        assert len(dispatch.autotune_cache()) == 3
+        # in_proj's 4384 columns are keyed (and timed) as 4480, not 8192
+        dispatch.blocks_for("nn", 512, 4384, 1024, interpret=False)
+        assert ("nn", 512, 4480, 1024) in dispatch.autotune_cache()
     finally:
         dispatch.reset_autotune()
         dispatch.set_autotune(measure=True)
+
+
+# (kind, R, C, D) of the mamba2_370m training cell's qmatmuls (in_proj,
+# out_proj, tied head; forward, dgrad, wgrad at 512 tokens), then one
+# decode-sized call (R = 8) that the serving path makes.
+CELL_SHAPES = [("nn", 512, 4384, 1024), ("nt", 512, 1024, 4384),
+               ("tn", 1024, 4384, 512), ("nn", 512, 1024, 2048),
+               ("nt", 512, 2048, 1024), ("tn", 2048, 1024, 512),
+               ("nt", 512, 50432, 1024), ("nn", 512, 1024, 50432),
+               ("tn", 50432, 1024, 512)]
+DECODE_SHAPES = [("nt", 8, 4384, 1024)]
+
+
+@pytest.mark.parametrize("kind,R,C,D", CELL_SHAPES + DECODE_SHAPES)
+def test_qmm_candidates_aligned_fitting_and_tight(kind, R, C, D):
+    """Every candidate is aligned (8 on a sublane-only axis, else 128),
+    fits the VMEM estimate and pads each real dim by a few percent at
+    most; a training shape's set holds a tile of at least 64 M MACs."""
+    key = dispatch._qmm_key(kind, R, C, D)
+    cands = dispatch._qmm_candidates(*key)
+    assert cands and len(set(cands)) == len(cands)
+    sub = dispatch._sublane_only(kind)
+    for blocks in cands:
+        assert dispatch._fits(kind, blocks, *key[1:]), blocks
+        assert dispatch._qmm_vmem(*blocks) <= dispatch._QMM_VMEM_BUDGET
+        for b, n, s in zip(blocks, (R, C, D), sub):
+            assert b % (8 if s else 128) == 0, blocks
+            padded = -(-n // b) * b
+            assert padded <= max(n * 1.05, -(-n // 8) * 8 if s else 128), \
+                (blocks, n, padded)
+    assert cands == sorted(cands, key=lambda c: dispatch._qmm_order(
+        c, *key[1:]))
+    assert tuple(min(n, 128) for n in key[1:]) in cands   # the smallest
+    if R >= 128:
+        assert max(b[0] * b[1] * b[2] for b in cands) >= 64 * 2**20
+    else:   # a decode row block spans the rows and no more
+        assert {b[0] for b in cands} == {-(-R // 8) * 8}
+
+
+def test_qmm_tie_break_is_deterministic(monkeypatch):
+    """Timings within 3% of the fastest are a tie, settled by fewest grid
+    steps, then least padding, then the tile: the same winner whatever
+    order or noise the timings come in."""
+    key = dispatch._qmm_key("nn", 512, 4384, 1024)
+    cands = dispatch._qmm_candidates(*key)
+    assert len(cands) > 2
+    want = (512, 896, 1024)  # 1 x 5 x 1 grid steps, the fewest
+    assert cands[0] == want
+    # grid steps rank before padding: 50432 columns in 50 tiles of 1024
+    # (1.5% padded) before 197 exact tiles of 256
+    head = dispatch._qmm_candidates(*dispatch._qmm_key("nt", 512, 50432,
+                                                       1024))
+    assert head[0] == (512, 1024, 1024)
+    assert head.index((512, 1024, 1024)) < head.index((512, 256, 1024))
+    rng = np.random.default_rng(0)
+    for trial in range(6):
+        # the slowest tie (+2.9%) on the tie-break's winner, a far faster
+        # one on no candidate: every candidate lies within 3%
+        noise = rng.uniform(0.0, 0.029, len(cands))
+        noise[0] = 0.029
+        times = {c: 1e-3 * (1 + x) for c, x in zip(cands, noise)}
+        order = list(rng.permutation(len(cands)))
+        monkeypatch.setattr(dispatch, "_timings", lambda k, cs, call: {
+            cs[i]: times[cs[i]] for i in order})
+        monkeypatch.setattr(dispatch, "qmm", None)
+        assert dispatch._measure(*key, None, 10) == want
+    # outside the tie window the fastest wins outright
+    times = {c: 1e-3 for c in cands}
+    times[cands[-1]] = 0.9e-3
+    monkeypatch.setattr(dispatch, "_timings", lambda k, cs, call: times)
+    assert dispatch._measure(*key, None, 10) == cands[-1]
 
 
 def test_autotune_counts_refused_and_raises_when_all_refused(monkeypatch):
@@ -175,21 +255,29 @@ def test_autotune_counts_refused_and_raises_when_all_refused(monkeypatch):
 
 def test_autotune_runs_candidates_eagerly_while_a_jit_traces(monkeypatch):
     """Block selection runs while a jit traces the kernel's caller, under
-    the serving mesh when sharded.  The candidates must still get real
-    arrays on one device: traced ones would time the tracing and never
-    meet a compile error, and mesh-sharded ones a Mosaic kernel refuses."""
+    the serving mesh when sharded.  The candidates must still run, to
+    completion, on real arrays on one device: traced ones would time the
+    tracing and never meet a compile error, and mesh-sharded ones a
+    Mosaic kernel refuses.  (Each timed program loops the kernel on the
+    device, so the kernel itself is traced there; what the timing waits
+    on is checked.)"""
     from jax.sharding import SingleDeviceSharding
 
     from repro.launch.mesh import make_serve_mesh
 
     seen = []
+    ready = jax.block_until_ready
 
     def eager_qmm(a, b, e_a, e_b, *, blocks, **kw):
-        traced = isinstance(a, jax.core.Tracer)
-        seen.append((traced, None if traced else a.sharding))
         return a[:1, 0] + b[:1, 0]
 
+    def waited_on(x):
+        traced = isinstance(x, jax.core.Tracer)
+        seen.append((traced, None if traced else x.sharding))
+        return ready(x)
+
     monkeypatch.setattr(dispatch, "qmm", eager_qmm)
+    monkeypatch.setattr(dispatch.jax, "block_until_ready", waited_on)
     monkeypatch.setattr(dispatch, "save_autotune", lambda *a, **kw: None)
     saved = dict(dispatch._BLOCK_CACHE)
     dispatch.reset_autotune()

@@ -87,6 +87,33 @@ def test_qmatmul_compiles(one_chip, arch, kind):
                  S((), jnp.float32))
 
 
+# (kind, R, C, D, width_b) of mamba2_370m's qmatmuls at 512 tokens, as
+# tape_dot calls them: raw activations and cotangents (width_a None), the
+# weight rounded in the forward and dgrad, both raw in the wgrad.
+MAMBA2_QMM = [("nn", 512, 4384, 1024, 10), ("nt", 512, 1024, 4384, 10),
+              ("tn", 1024, 4384, 512, None), ("nn", 512, 1024, 2048, 10),
+              ("nt", 512, 2048, 1024, 10), ("tn", 2048, 1024, 512, None),
+              ("nt", 512, 50432, 1024, 10), ("nn", 512, 1024, 50432, 10),
+              ("tn", 50432, 1024, 512, None)]
+
+
+@pytest.mark.parametrize("kind,R,C,D,width_b", MAMBA2_QMM)
+def test_qmatmul_ranked_tiles_compile(one_chip, kind, R, C, D, width_b):
+    """The largest tile the dispatch layer offers a shape (its first in
+    tie-break order) fits the chip's VMEM at the kernel's scoped limit."""
+    key = dispatch._qmm_key(kind, R, C, D)
+    blocks = dispatch._qmm_candidates(*key)[0]
+    assert blocks[0] * blocks[1] * blocks[2] >= 64 * 2**20
+    a_shape = {"nn": (R, D), "nt": (R, D), "tn": (D, R)}[kind]
+    b_shape = {"nn": (D, C), "nt": (C, D), "tn": (D, C)}[kind]
+    S = _sds(one_chip)
+    _compile(lambda a, b, e: qmm(a, b, e, e, kind=kind, width_a=None,
+                                 width_b=width_b, blocks=blocks,
+                                 interpret=False),
+             S(a_shape, jnp.float32), S(b_shape, jnp.float32),
+             S((), jnp.float32))
+
+
 def test_dfxp_quantize_compiles(one_chip):
     S = _sds(one_chip)
     _compile(lambda x, s, i: dfxp_quantize_2d(x, s, i, width=10,
